@@ -61,6 +61,7 @@ from .backends import (
     SyncScoreboard,
     SyncSemantics,
     UnknownBackendError,
+    backend_for_device_kind,
     get_backend,
     list_backends,
     register_backend,
@@ -154,7 +155,8 @@ __all__ = [
     "SchedulerContentionBlame",
     "SyncModel", "SyncPressureReport", "SyncResourceBlame",
     "SyncResourcePool", "SyncScoreboard", "SyncSemantics",
-    "UnknownBackendError", "get_backend", "list_backends",
+    "UnknownBackendError", "backend_for_device_kind", "get_backend",
+    "list_backends",
     "register_backend", "resolve_backend", "resolve_sync_model",
     # pass pipeline
     "AnalysisContext", "AnalysisPass", "DEFAULT_PIPELINE",

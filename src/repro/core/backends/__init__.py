@@ -212,6 +212,7 @@ GENERIC_TAXONOMY: Mapping[StallClass, str] = {
 # -- default registrations ---------------------------------------------------
 # Imported last: the vendor modules call register_backend() at import time.
 from . import amd, intel, nvidia, tpu  # noqa: E402,F401  (registration side effect)
+from .tpu import backend_for_device_kind  # noqa: E402
 
 __all__ = [
     "Backend", "BackendRegistry", "BackendLike", "IssueModel",
@@ -221,4 +222,5 @@ __all__ = [
     "SyncSemantics", "resolve_sync_model",
     "UnknownBackendError", "REGISTRY", "GENERIC_TAXONOMY",
     "register_backend", "get_backend", "list_backends", "resolve_backend",
+    "backend_for_device_kind",
 ]

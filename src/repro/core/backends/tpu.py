@@ -8,7 +8,8 @@ from __future__ import annotations
 
 from ..hwmodel import TPU_V4, TPU_V5E, TPU_V5P
 from ..isa import StallClass, SyncKind
-from . import Backend, SyncModel, SyncResourcePool, register_backend
+from . import (Backend, SyncModel, SyncResourcePool, get_backend,
+               register_backend)
 
 TPU_TAXONOMY = {
     StallClass.NONE: "idle",
@@ -67,3 +68,19 @@ TPU_V4_BACKEND = register_backend(Backend(
     name="tpu_v4", vendor="google", hw=TPU_V4,
     stall_taxonomy=TPU_TAXONOMY, sync=TPU_SYNC,
     description="TPU v4: balanced mid-generation part."))
+
+# `jax.Device.device_kind` of each chip -> the backend that models it.  A
+# kind that is not listed has no model here: callers raise, they do not
+# fall back to another chip's constants.
+DEVICE_KIND_BACKENDS = {
+    "TPU v5 lite": TPU_V5E_BACKEND.name,
+    "TPU v4": TPU_V4_BACKEND.name,
+}
+
+
+def backend_for_device_kind(kind: str) -> Backend:
+    """The registered backend that models the chip JAX reports as `kind`."""
+    if kind not in DEVICE_KIND_BACKENDS:
+        raise ValueError(f"no LEO backend models device kind {kind!r}; "
+                         f"known kinds: {sorted(DEVICE_KIND_BACKENDS)}")
+    return get_backend(DEVICE_KIND_BACKENDS[kind])

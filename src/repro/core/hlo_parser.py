@@ -177,6 +177,91 @@ def _replica_group_size(attr: str, total_devices: Optional[int]) -> int:
     return 1
 
 
+def _parse_window(attr: str, rank: int) -> List[dict]:
+    """Per-spatial-dimension stride, padding and dilations of a convolution's
+    ``window={size=4x14 stride=3x13 pad=3_3x13_13 lhs_dilate=4x14}``
+    attribute, with HLO's defaults for fields the text leaves out (the
+    window size is the kernel's spatial extent, read from its shape)."""
+    dims = [{"stride": 1, "pad": (0, 0), "lhs_dilate": 1, "rhs_dilate": 1}
+            for _ in range(rank)]
+    for tok in attr.strip("{} ").split():
+        key, _, value = tok.partition("=")
+        if key not in ("stride", "pad", "lhs_dilate", "rhs_dilate"):
+            continue
+        for d, field in zip(dims, value.split("x")):
+            if key == "pad":
+                lo, _, hi = field.partition("_")
+                d["pad"] = (int(lo), int(hi))
+            else:
+                d[key] = int(field)
+    return dims
+
+
+def _valid_taps(in_n: int, out_n: int, k_n: int, stride: int, pad_lo: int,
+                lhs_dilate: int, rhs_dilate: int) -> int:
+    """Number of (output, kernel) position pairs along one spatial dimension
+    that land on a real input element: padding and the holes that
+    ``lhs_dilate`` inserts contribute no multiply-adds.  Closed form per
+    kernel tap of the count XLA's cost analysis takes by enumeration."""
+    total = 0
+    hi = (in_n - 1) * lhs_dilate      # last undilated input coordinate
+    for k in range(k_n):
+        c = k * rhs_dilate - pad_lo   # undilated coordinate = o * stride + c
+        o_min = max(0, -(c // stride))
+        o_max = min(out_n - 1, (hi - c) // stride)
+        if o_max < o_min:
+            continue
+        if lhs_dilate == 1:
+            total += o_max - o_min + 1
+            continue
+        # o * stride + c must also be a multiple of lhs_dilate
+        g = math.gcd(stride, lhs_dilate)
+        if c % g:
+            continue
+        m = lhs_dilate // g
+        o0 = (-c // g) * pow(stride // g, -1, m) % m if m > 1 else 0
+        first = o_min + (o0 - o_min) % m
+        if first <= o_max:
+            total += (o_max - first) // m + 1
+    return total
+
+
+def _convolution_flops(instr: Instruction, lhs: ShapeInfo,
+                       rhs: ShapeInfo) -> float:
+    """Multiply-add FLOPs of one HLO convolution.
+
+    The TPU compiler emits every matmul, batched einsum included, as a
+    ``convolution`` whose ``dim_labels`` (``lhs_rhs->out``: ``b``/``f`` batch
+    and feature, ``i``/``o`` kernel input/output feature, digits spatial)
+    name the contraction, and whose ``window`` may stride and dilate the
+    spatial dimensions so that each output sees a single real tap.  So
+    count 2 x (input features per group) x output features x (batch per
+    group) x the valid taps of every spatial dimension, as XLA's own cost
+    analysis does.
+    """
+    labels = instr.attributes.get("dim_labels", "")
+    m = re.match(r"([^_]+)_([^-]+)->(.+)$", labels)
+    if not m or len(m.group(1)) != len(lhs.dims) or \
+            len(m.group(2)) != len(rhs.dims) or \
+            len(m.group(3)) != len(instr.shape.dims):
+        return 2.0 * instr.shape.num_elements * rhs.num_elements
+    lhs_l, rhs_l, out_l = m.groups()
+    rank = sum(ch.isdigit() for ch in lhs_l)
+    window = _parse_window(instr.attributes.get("window", ""), rank)
+    taps = 1
+    for s, w in enumerate(window):
+        taps *= _valid_taps(
+            lhs.dims[lhs_l.index(str(s))], instr.shape.dims[out_l.index(str(s))],
+            rhs.dims[rhs_l.index(str(s))], w["stride"], w["pad"][0],
+            w["lhs_dilate"], w["rhs_dilate"])
+    fgc = int(instr.attributes.get("feature_group_count", "1"))
+    bgc = int(instr.attributes.get("batch_group_count", "1"))
+    in_feat = lhs.dims[lhs_l.index("f")] // fgc
+    out_feat = instr.shape.dims[out_l.index("f")]
+    batch = lhs.dims[lhs_l.index("b")] // bgc
+    return 2.0 * in_feat * out_feat * batch * taps
+
+
 class HloParser:
     """Parse optimized HLO module text into the unified instruction model."""
 
@@ -378,11 +463,10 @@ class HloParser:
                     if di < len(lhs.shape.dims):
                         k *= lhs.shape.dims[di]
             instr.flops = 2.0 * out_elems * k
-        elif opc == "convolution":
-            # approximation: 2 * out_elems * kernel_elems
-            rhs = comp.get(instr.operands[1]) if len(instr.operands) > 1 else None
-            kern = rhs.shape.num_elements if rhs is not None else 1
-            instr.flops = 2.0 * out_elems * kern
+        elif opc == "convolution" and len(instr.operands) == 2:
+            lhs, rhs = (comp.get(o) for o in instr.operands)
+            if lhs is not None and rhs is not None:
+                instr.flops = _convolution_flops(instr, lhs.shape, rhs.shape)
         elif cls is OpClass.REDUCE:
             in_elems = 0
             for op_name_ in instr.operands:

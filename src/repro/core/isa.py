@@ -264,6 +264,8 @@ class Module:
         total = 0.0
         for instr in self.computations[comp_name].instructions:
             total += mult * instr.flops
+            if instr.opcode == "fusion":
+                continue  # the parser folds a fusion body's flops into it
             inner_mult = mult * (instr.trip_count if trip_aware else 1)
             for callee in instr.called_computations:
                 total += self._comp_flops(callee, inner_mult, trip_aware, stack)

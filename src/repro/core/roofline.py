@@ -129,9 +129,6 @@ def compute_roofline(
         collective_breakdown={k: v.wire_bytes for k, v in colls.items()},
     )
     if cost_analysis:
-        # jax >= 0.4.30 returns a one-element list of per-module dicts
-        if isinstance(cost_analysis, (list, tuple)):
-            cost_analysis = cost_analysis[0] if cost_analysis else {}
         report.xla_flops_per_device = float(cost_analysis.get("flops", 0.0))
         report.xla_bytes_per_device = float(
             cost_analysis.get("bytes accessed", 0.0))

@@ -6,7 +6,8 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
     persists across key-block grid steps — no atomics or shared-memory
     staging as on GPUs;
   * BlockSpec index maps pin one (batch, q-head) pair per outer step and
-    stream (block_q x head_dim) / (block_k x head_dim) tiles through VMEM;
+    stream (block_q x head_dim) / (block_k x head_dim) tiles of head-major
+    copies of q/k/v through VMEM;
     GQA maps the q-head grid index onto its KV head in the index map, so
     KV tiles are fetched once per group without materializing repeats;
   * block shapes default to 128 x head_dim — MXU-aligned (128 lanes) and
@@ -38,12 +39,6 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
-    first_ik = 0
-    if causal and window is not None:
-        # lowest key block the window can reach (static bound is grid-wide;
-        # dynamic skip below handles per-iq bands)
-        pass
-
     @pl.when(ik == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -59,9 +54,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(in_band)
     def _compute():
-        q = q_ref[0, :, 0, :]                       # (Bq, hd)
-        k = k_ref[0, :, 0, :]                       # (Bk, hd)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]                             # (Bq, hd)
+        k = k_ref[0, 0]                             # (Bk, hd)
+        v = v_ref[0, 0]
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (Bq, Bk)
@@ -76,13 +71,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             mask = jnp.logical_and(mask, k_pos > q_pos - window)
         scores = jnp.where(mask, scores, _NEG_INF)
 
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                         # (Bq, 1)
         l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, scores.max(axis=1))
-        p = jnp.exp(scores - m_new[:, None])
+        m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
+        p = jnp.exp(scores - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -92,15 +87,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ik == last_ik)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(
-            o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
-    """q (B,S,H,hd); k/v (B,S,Kv,hd) with H % Kv == 0. Returns (B,S,H,hd)."""
+    """q (B,S,H,hd); k/v (B,S,Kv,hd) with H % Kv == 0. Returns (B,S,H,hd).
+
+    The kernel runs on head-major (B,H,S,hd) copies: Mosaic tiles the last
+    two block dimensions, which must be (block x hd), not (1 x hd)."""
     b, s, h, hd = q.shape
     kv_heads = k.shape[2]
     groups = h // kv_heads
@@ -116,28 +113,22 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kernel = functools.partial(
         _flash_kernel, scale=scale, block_q=block_q, block_k=block_k,
         n_kv=n_kv, causal=causal, window=window)
-
-    grid = (b, h, n_q, n_kv)
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((1, 1, block_q, hd),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
+                           lambda bi, hi, qi, ki, g=groups:
+                           (bi, hi // g, ki, 0))
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, qi, ki, g=groups:
-                         (bi, ki, hi // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, qi, ki, g=groups:
-                         (bi, ki, hi // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, hd), q.dtype),
+        grid=(b, h, n_q, n_kv),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2))
+    return out.swapaxes(1, 2)

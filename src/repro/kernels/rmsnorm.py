@@ -101,7 +101,7 @@ def rmsnorm_pipelined(x: jnp.ndarray, scale: jnp.ndarray, *,
         functools.partial(_rmsnorm_pipelined_kernel, eps=eps,
                           block_rows=block_rows, n_blocks=n_blocks),
         grid=(n_blocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),

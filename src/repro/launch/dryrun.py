@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this:
@@ -24,12 +21,22 @@ Usage:
 import argparse
 import gzip
 import json
+import os
 import time
 import traceback
-from functools import partial
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+
+def use_host_devices(count: int = 512) -> None:
+    """Run on the CPU backend with `count` virtual devices, enough for the
+    production meshes.  Appends to any XLA_FLAGS already set; call before
+    the process first touches a device."""
+    flag = f"--xla_force_host_platform_device_count={count}"
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (os.environ.get("XLA_FLAGS", ""), flag) if f)
+    jax.config.update("jax_platforms", "cpu")
 
 
 def _sharding_tree(mesh, spec_tree):
@@ -248,6 +255,7 @@ def main() -> None:
                     help="dump the analysis-cache/latency metrics "
                          "(Prometheus text format) to PATH after the sweep")
     args = ap.parse_args()
+    use_host_devices()
     model_flags = _parse_flags(args.flags)
 
     archs = [c.name for c in ALL_ARCHS] if args.arch == "all" \

@@ -72,11 +72,8 @@ CELLS = {
 def run_variant(arch, shape_name, name, model_flags, opt_overrides,
                 mesh_kind, outdir, hw_name="tpu_v5e", analyze=True,
                 force=False):
-    # jax and the host-device XLA flag are only needed when actually
-    # lowering; importing here keeps the what-if search path light
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-    import jax  # noqa: F401
-
+    # jax is only needed when actually lowering; importing here keeps the
+    # what-if search path light
     from ..configs import get_config, get_shape, model_flops
     from ..core import get_backend
     from ..core.roofline import compute_roofline
@@ -361,6 +358,8 @@ def main():
         return
     if args.cell is None:
         ap.error("--cell is required unless --whatif or --rewrite is given")
+    from .dryrun import use_host_devices
+    use_host_devices()
     spec = CELLS[args.cell]
     for name, model_flags, opt_overrides in spec["variants"]:
         run_variant(spec["arch"], spec["shape"], name, model_flags,

@@ -129,7 +129,9 @@ def main(argv=None) -> Dict[int, List[int]]:
 
     from ..configs import get_config, smoke_config
     from ..models import init_params
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = smoke_config(get_config(args.arch))
     params = init_params(jax.random.PRNGKey(0), cfg)
     engine = ServeEngine(cfg, params, args.slots, args.max_len)

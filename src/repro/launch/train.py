@@ -2,22 +2,22 @@
 checkpointing -> fault handling -> (optional) LEO analysis of the compiled
 step.
 
-On this CPU container it drives reduced configs (`--smoke`) on a host mesh;
-on real pods the same driver runs the production mesh (the dry-run proves
-those configs lower/compile).  Example:
+On a CPU it drives reduced configs (`--smoke`) on a host mesh; on a TPU the
+same driver runs published widths over the chips of the host.  `--analyze`
+diagnoses the step's own compiled program on the LEO backend that models
+the chip it ran on, so it needs a chip that backend table lists.  Example:
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --smoke \
-      --steps 50 --batch 8 --seq 64 --checkpoint-dir /tmp/ckpt --analyze
+      --steps 50 --batch 8 --seq 64 --checkpoint-dir ckpt
+  python -m repro.launch.train --arch qwen2-0.5b --steps 10 --batch 4 \
+      --seq 1024 --analyze            # on a TPU, PYTHONPATH=src
 """
 from __future__ import annotations
 
 import argparse
 import time
-from functools import partial
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
@@ -99,7 +99,12 @@ def main(argv=None) -> dict:
                     help="run LEO on the compiled train step")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
     from .mesh import make_host_mesh
+    enable_compile_cache()
+    devices = jax.devices()
+    print(f"devices: platform={devices[0].platform} "
+          f"kind={devices[0].device_kind} count={len(devices)}")
     mesh = make_host_mesh(model_parallel=args.model_parallel)
 
     with mesh:
@@ -119,12 +124,14 @@ def main(argv=None) -> dict:
                     state, shardings=state_sh)
                 print(f"restored from step {start_step}")
 
-        losses = []
+        losses, step_seconds = [], []
         t0 = time.time()
         it = pipeline(start_step)
         for step in range(start_step, args.steps):
             batch = next(it)
-            state, metrics = step_fn(state, batch)
+            t_step = time.perf_counter()
+            state, metrics = jax.block_until_ready(step_fn(state, batch))
+            step_seconds.append(time.perf_counter() - t_step)
             loss = float(metrics["loss"])
             losses.append(loss)
             if step % 10 == 0 or step == args.steps - 1:
@@ -137,24 +144,50 @@ def main(argv=None) -> dict:
             manager.wait()
         wall = time.time() - t0
 
+        params = jax.tree.leaves(state["params"])
         result = {"final_loss": losses[-1], "first_loss": losses[0],
-                  "steps": args.steps - start_step, "wall_seconds": wall}
+                  "losses": losses, "step_seconds": step_seconds,
+                  "steps": args.steps - start_step, "wall_seconds": wall,
+                  "param_devices": len({d for p in params
+                                        for d in p.sharding.device_set}),
+                  "split_params": sum(not p.sharding.is_fully_replicated
+                                      for p in params)}
         print(f"done: loss {losses[0]:.4f} -> {losses[-1]:.4f} "
               f"({result['steps']} steps, {wall:.1f}s)")
 
         if args.analyze:
-            from ..core import LeoSession
-            from ..launch import specs as S
-            lowered = jax.jit(step_fn.__wrapped__).lower(
-                jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                             state),
-                jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                             pipeline.device_batch(0)))
-            an = LeoSession().analyze(lowered.compile().as_text(),
-                                      backend="tpu_v5e")
-            print(an.summary())
-            result["leo_step_seconds"] = an.estimated_step_seconds
+            result.update(_analyze_step(
+                cfg, step_fn.lower(state, pipeline.device_batch(0)),
+                args.batch, args.seq, len(devices), devices[0].device_kind))
         return result
+
+
+def _analyze_step(cfg, lowered, batch: int, seq: int, chips: int,
+                  device_kind: str) -> dict:
+    """LEO on the train step's own compiled program, priced on the backend
+    that models `device_kind`."""
+    from ..configs import model_flops
+    from ..configs.base import ShapeConfig
+    from ..core import LeoSession, backend_for_device_kind
+    from ..core.roofline import compute_roofline
+
+    backend = backend_for_device_kind(device_kind)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    hints = {"total_devices": chips}
+    session = LeoSession()
+    an = session.analyze(text, backend=backend, hints=hints)
+    print(an.summary())
+    roofline = compute_roofline(
+        session.parse(text, hints=hints), backend.hw, chips=chips,
+        model_flops=model_flops(cfg, ShapeConfig("run", seq, batch, "train")),
+        cost_analysis=compiled.cost_analysis(),
+        memory_analysis=compiled.memory_analysis())
+    print(roofline.summary_row())
+    return {"leo_backend": backend.name,
+            "leo_step_seconds": an.estimated_step_seconds,
+            "useful_ratio": roofline.useful_ratio,
+            "compiled_text": text}
 
 
 if __name__ == "__main__":

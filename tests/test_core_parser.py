@@ -3,7 +3,7 @@ collectives, metadata — validated against both fixtures and a real compiled
 XLA program."""
 import pytest
 
-from repro.core.hlo_parser import parse_hlo, parse_shape
+from repro.core.hlo_parser import _valid_taps, parse_hlo, parse_shape
 from repro.core.isa import OpClass, ShapeInfo, SyncKind
 from repro.core.collectives import (
     collective_operand_bytes,
@@ -94,14 +94,9 @@ class TestFixtureParsing:
 class TestAgainstRealXLA:
     def test_flops_match_cost_analysis(self, small_compiled_step):
         ca = small_compiled_step.cost_analysis()
-        # jax >= 0.4.30 returns one properties dict per executable program
-        # (a list); older versions returned the dict bare.  Our single-jit
-        # fixture has exactly one program either way.
-        if isinstance(ca, list):
-            ca = ca[0]
         mod = parse_hlo(small_compiled_step.as_text())
         # XLA counts loop bodies once; our trip-unaware total should agree
-        # within 20% (fusion/layout noise; measured ~4.5% on jax 0.4.37).
+        # within 20% (fusion/layout noise).
         ours = mod.total_flops(trip_aware=False)
         assert ours == pytest.approx(ca["flops"], rel=0.2)
 
@@ -113,6 +108,61 @@ class TestAgainstRealXLA:
         mod = parse_hlo(small_compiled_step.as_text())
         for instr in mod.all_instructions():
             assert isinstance(instr.shape, ShapeInfo)
+
+
+def _taps_by_enumeration(in_n, out_n, k_n, stride, pad_lo, lhs_dilate,
+                         rhs_dilate):
+    """The (output, kernel) pairs that hit a real input element, counted
+    one by one as XLA's cost analysis does."""
+    count = 0
+    for k in range(k_n):
+        for o in range(out_n):
+            u = o * stride - pad_lo + k * rhs_dilate
+            if u % lhs_dilate == 0 and 0 <= u // lhs_dilate < in_n:
+                count += 1
+    return count
+
+
+_CONV_HLO = """\
+HloModule conv_fixture
+
+ENTRY %main (a: bf16[4,512,14,64], b: bf16[4,14,512,512], c: bf16[896,896,1], d: bf16[4,1024,896]) -> (f32[4,14,512,64], bf16[4,1024,896]) {
+  %a = bf16[4,512,14,64]{1,3,2,0} parameter(0)
+  %b = bf16[4,14,512,512]{2,3,1,0} parameter(1)
+  %c = bf16[896,896,1]{1,0,2} parameter(2)
+  %d = bf16[4,1024,896]{1,2,0} parameter(3)
+  %attn = f32[4,14,512,64]{2,3,1,0} convolution(%a, %b), window={size=4x14 stride=4x14 pad=3_3x13_13 lhs_dilate=3x13 rhs_reversal=1x1}, dim_labels=0f1b_01oi->01fb
+  %proj = bf16[4,1024,896]{1,2,0} convolution(%c, %d), window={size=4 pad=3_3 rhs_reversal=1}, dim_labels=bf0_0oi->0fb
+  ROOT %out = (f32[4,14,512,64], bf16[4,1024,896]) tuple(%attn, %proj)
+}
+"""
+
+
+class TestConvolutionFlops:
+    """The TPU compiler's convolutions: each output of a window that the
+    padding and lhs dilation leave one real tap costs one multiply-add per
+    (input feature, output feature, batch) triple, not a whole window."""
+
+    @pytest.mark.parametrize("seed", range(4))
+    def test_valid_taps_match_enumeration(self, seed):
+        import random
+        rng = random.Random(seed)
+        for _ in range(500):
+            case = (rng.randint(1, 12), rng.randint(1, 12),
+                    rng.randint(1, 12), rng.randint(1, 5),
+                    rng.randint(-4, 6), rng.randint(1, 5),
+                    rng.randint(1, 4))
+            assert _valid_taps(*case) == _taps_by_enumeration(*case), case
+
+    def test_base_dilated_attention_gradient(self):
+        conv = parse_hlo(_CONV_HLO).entry_computation.get("attn")
+        # one tap per output along both windowed dims (batch 4, heads 14)
+        assert conv.flops == 2 * 512 * 512 * 64 * 4 * 14
+
+    def test_padded_batch_window(self):
+        conv = parse_hlo(_CONV_HLO).entry_computation.get("proj")
+        # a 896 x 896 weight gradient over 4 x 1024 tokens
+        assert conv.flops == 2 * 896 * 896 * 4 * 1024
 
 
 class TestCollectiveExtraction:
