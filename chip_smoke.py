@@ -17,10 +17,11 @@ With no option it drives, in one process:
   5. each Pallas kernel compiled by Mosaic at a real model width and
      checked against its oracle in `repro.kernels.ref`.
 
-`--chips 4` runs only the trainer on a 2x2 data x model mesh, compares its
-first-step loss with a one-chip mesh in the same process, checks that the
-parameter shards cover the four chips, and reads the step's collective
-bytes through LEO.
+`--chips 4` runs only the trainer on a 2x2 data x model mesh and the same
+steps on a one-chip mesh in this process.  It compares the loss and
+gradient norm of every step and the parameter update over the run, checks
+that the parameter shards cover the four chips, and reads the step's
+collective bytes through LEO.
 
 Any failed check raises and ends the run with a non-zero code.  The last
 line of standard output is the result as one JSON object.  Step times
@@ -43,16 +44,26 @@ if os.environ.get("JAX_PLATFORMS") and \
         "cpu" not in os.environ["JAX_PLATFORMS"].split(","):
     os.environ["JAX_PLATFORMS"] += ",cpu"
 
-ARCH = "qwen2-0.5b"
-TRAIN_ARGS = ["--arch", ARCH, "--steps", "10", "--batch", "4",
-              "--seq", "1024"]
-SHARDED_TRAIN_ARGS = ["--arch", ARCH, "--steps", "3", "--batch", "4",
-                      "--seq", "1024", "--model-parallel", "2"]
+ARCH, BATCH, SEQ = "qwen2-0.5b", 4, 1024
+TRAIN_ARGS = ["--arch", ARCH, "--steps", "10", "--batch", str(BATCH),
+              "--seq", str(SEQ)]
+# The step-0 learning rate is 0 (one warmup step), so the last of 4 steps
+# comes after two updates.
+SHARDED_STEPS = 4
+SHARDED_TRAIN_ARGS = ["--arch", ARCH, "--steps", str(SHARDED_STEPS),
+                      "--batch", str(BATCH), "--seq", str(SEQ),
+                      "--model-parallel", "2"]
 REF_TOKENS = (1, 256)           # the fixed batch of the chip/CPU check
-# bf16 weights and activations: the chip and the CPU round differently,
-# but the mean loss over 256 tokens agrees far inside this bound.
-REF_LOSS_RTOL = 5e-3
-SHARDED_LOSS_RTOL = 5e-3        # 2x2 mesh vs one chip, same batch
+# Bounds on relative differences, each about 5x above the gap measured on
+# a v5e (in brackets; PERF.md).  Both sides of a comparison run the same
+# programs on the same inputs, so the gaps repeat from run to run.
+REF_LOSS_RTOL = 1e-3            # chip vs CPU forward loss (1.9e-4)
+# 2x2 mesh vs one chip.  Before any update the two differ only in the order
+# of reductions; each update widens the gap.  A run that trains on half of
+# each batch lands 0.41 away in step-0 gradient norm and 0.88 in update.
+SHARDED_STEP0_RTOL = 6e-4       # step-0 loss, grad norm (5.6e-5, 1.2e-4)
+SHARDED_STEP_RTOL = 2e-2        # later steps (3.0e-3, 3.7e-3)
+SHARDED_UPDATE_RTOL = 0.35      # |dW_2x2 - dW_1| / |dW_1| (0.074)
 USEFUL_RATIO_RANGE = (0.5, 1.05)
 
 
@@ -91,10 +102,10 @@ def device_gate(chips: int):
     return devices[:chips], backend
 
 
-def train_phase(train_args) -> dict:
+def train_phase() -> dict:
     from repro.launch import train
 
-    result = train.main(train_args + ["--analyze"])
+    result = train.main(TRAIN_ARGS + ["--analyze"])
     losses = result["losses"]
     check(all(math.isfinite(x) for x in losses), f"non-finite loss: {losses}")
     check(losses[-1] < losses[0],
@@ -107,16 +118,15 @@ def train_phase(train_args) -> dict:
     return result
 
 
-def reference_phase(arch: str, smoke: bool, tokens_shape) -> float:
+def reference_phase() -> float:
     """Forward loss of one fixed batch on the default device and on the
     CPU backend of this process, from the same parameters."""
     import jax
-    from repro.configs import get_config, smoke_config
+    from repro.configs import get_config
     from repro.models import init_params, loss_fn
 
-    cfg = get_config(arch)
-    cfg = smoke_config(cfg) if smoke else cfg
-    tokens = jax.random.randint(jax.random.PRNGKey(1), tokens_shape, 0,
+    cfg = get_config(ARCH)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), REF_TOKENS, 0,
                                 cfg.vocab_size)
     batch = {"tokens": tokens, "labels": jax.numpy.roll(tokens, -1, axis=1)}
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -125,18 +135,18 @@ def reference_phase(arch: str, smoke: bool, tokens_shape) -> float:
     cpu = jax.devices("cpu")[0]
     cpu_loss = float(fwd(jax.device_put(params, cpu),
                          jax.device_put(batch, cpu)))
-    rel = abs(chip_loss - cpu_loss) / abs(cpu_loss)
-    print(f"forward loss on {tokens_shape}: device {chip_loss:.6f} "
+    rel = _rel(chip_loss, cpu_loss)
+    print(f"forward loss on {REF_TOKENS}: device {chip_loss:.6f} "
           f"cpu {cpu_loss:.6f} rel diff {rel:.3e} (bound {REF_LOSS_RTOL})")
     check(rel <= REF_LOSS_RTOL, "device loss disagrees with the CPU")
     return rel
 
 
-def leo_phase(result: dict, backend, chips: int) -> dict:
+def leo_phase(result: dict, backend) -> dict:
     from repro.core import LeoService
 
     text = result["compiled_text"]
-    hints = {"total_devices": chips}
+    hints = {"total_devices": 1}
     svc = LeoService()
     diag = svc.diagnose(text, backend=backend.name, hints=hints)
     print(f"LEO {backend.name}: estimated step {diag.estimated_step_seconds:.4g}"
@@ -156,9 +166,10 @@ def leo_phase(result: dict, backend, chips: int) -> dict:
 
 
 def kernel_cases():
-    """(name, op, oracle, args builder, tolerance) at real model widths:
+    """(name, op, oracle, args builder, atol = rtol) at real model widths:
     flash at qwen2-0.5b heads, rmsnorm at its d_model, ssm at hymba-1.5b's
-    d_inner and state, mLSTM/sLSTM at xlstm-125m's."""
+    d_inner and state, mLSTM/sLSTM at xlstm-125m's.  The bf16 kernels get
+    about 2.5 bf16 ulps of 1.0, the f32 ones 1e-4."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops, ref
@@ -191,69 +202,105 @@ def kernel_cases():
         return (rand(13, (4, s // 4, 4 * d_sl)),
                 rand(14, (d_sl, 4 * d_sl)) * 0.1 / math.sqrt(d_sl / 64))
 
+    bf16_tol, f32_tol = 2e-2, 1e-4
     return [
         ("flash_attention", ops.flash_attention_op,
-         ref.flash_attention_ref, flash, 3e-2),
+         ref.flash_attention_ref, flash, bf16_tol),
         ("rmsnorm_baseline", ops.rmsnorm_baseline_op, ref.rmsnorm_ref, rms,
-         3e-2),
-        ("rmsnorm_pipelined", ops.rmsnorm_op, ref.rmsnorm_ref, rms, 3e-2),
+         bf16_tol),
+        ("rmsnorm_pipelined", ops.rmsnorm_op, ref.rmsnorm_ref, rms, bf16_tol),
         ("ssm_scan", lambda *a, **k: ops.ssm_scan_op(*a, chunk=64, **k),
-         ref.ssm_scan_ref, ssm, 1e-2),
+         ref.ssm_scan_ref, ssm, f32_tol),
         ("mlstm_chunkwise",
          lambda *a, **k: ops.mlstm_chunkwise_op(*a, chunk=64, **k),
-         ref.mlstm_ref, mlstm, 1e-2),
+         ref.mlstm_ref, mlstm, f32_tol),
         ("slstm_scan", lambda *a, **k: ops.slstm_scan_op(*a, chunk=64, **k),
-         ref.slstm_scan_ref, slstm, 1e-2),
+         ref.slstm_scan_ref, slstm, f32_tol),
     ]
 
 
-def kernels_phase(cases, interpret: bool = False) -> dict:
+def kernels_phase() -> dict:
+    """Each kernel compiled by Mosaic against its oracle at `highest`
+    matmul precision, within |out - ref| <= tol + tol * |ref|."""
     import jax
     import numpy as np
 
     errors = {}
-    for name, op, oracle, make_args, tol in cases:
+    for name, op, oracle, make_args, tol in kernel_cases():
         args = make_args()
-        kernel = jax.jit(lambda *a, op=op: op(*a, interpret=interpret))
+        kernel = jax.jit(lambda *a, op=op: op(*a, interpret=False))
         compiled = kernel.lower(*args).compile()
-        if not interpret:
-            check("tpu_custom_call" in compiled.as_text(),
-                  f"{name}: no Mosaic kernel in the compiled program")
+        check("tpu_custom_call" in compiled.as_text(),
+              f"{name}: no Mosaic kernel in the compiled program")
         out = np.asarray(compiled(*args), np.float32)
         with jax.default_matmul_precision("highest"):
             expect = np.asarray(jax.jit(oracle)(*args), np.float32)
-        err = float(np.max(np.abs(out - expect)))
-        errors[name] = err
-        print(f"  {name:18s} {tuple(args[0].shape)} max abs err {err:.3e} "
-              f"(tol {tol})")
-        np.testing.assert_allclose(out, expect, atol=tol, rtol=tol,
-                                   err_msg=name)
+        err = np.abs(out - expect)
+        used = float(np.max(err / (tol + tol * np.abs(expect))))
+        errors[name] = float(np.max(err))
+        print(f"  {name:18s} {tuple(args[0].shape)} max abs err "
+              f"{errors[name]:.3e}; worst err / ({tol} + {tol}|ref|) "
+              f"{used:.3e}")
+        check(used <= 1.0, f"{name}: output outside the bound")
     return errors
 
 
-def sharded_phase(devices, train_args, reference_args) -> dict:
-    """The trainer on a 2x2 data x model mesh against one chip."""
+def _host_leaves(tree):
     import jax
+    import numpy as np
+    return [np.asarray(x, np.float32) for x in jax.tree.leaves(tree)]
+
+
+def _rel(a, b) -> float:
+    return abs(a - b) / abs(b)
+
+
+def sharded_phase(devices) -> dict:
+    """The trainer on a 2x2 data x model mesh against one chip: the loss
+    and gradient norm of every step from the same initial state and
+    batches, and the parameter update over the run."""
+    import numpy as np
     from repro.core import collective_summary, parse_hlo
     from repro.launch import train
     from repro.launch.mesh import make_host_mesh
 
-    # First-step loss of the same initial state and batch on one chip.
-    arch, smoke, batch, seq = reference_args
     mesh = make_host_mesh(1, devices=devices[:1])
     with mesh:
-        _, state, _, pipeline, step_fn = train.build(arch, smoke, batch, seq,
-                                                     mesh)
-        _, metrics = step_fn(state, pipeline.device_batch(0))
-        one_chip_loss = float(metrics["loss"])
+        _, state, _, pipeline, step_fn = train.build(
+            ARCH, False, BATCH, SEQ, mesh, steps=SHARDED_STEPS)
+        initial = _host_leaves(state["params"])
+        one_chip = {"losses": [], "grad_norms": []}
+        for i in range(SHARDED_STEPS):
+            state, metrics = step_fn(state, pipeline.device_batch(i))
+            one_chip["losses"].append(float(metrics["loss"]))
+            one_chip["grad_norms"].append(float(metrics["grad_norm"]))
+        one_chip_final = _host_leaves(state["params"])
     del state, metrics
 
-    result = train.main(train_args + ["--analyze"])
-    rel = abs(result["first_loss"] - one_chip_loss) / abs(one_chip_loss)
-    print(f"first-step loss: 2x2 mesh {result['first_loss']:.6f} one chip "
-          f"{one_chip_loss:.6f} rel diff {rel:.3e} "
-          f"(bound {SHARDED_LOSS_RTOL})")
-    check(rel <= SHARDED_LOSS_RTOL, "sharded loss disagrees with one chip")
+    result = train.main(SHARDED_TRAIN_ARGS + ["--analyze"])
+    worst = 0.0
+    for i in range(SHARDED_STEPS):
+        bound = SHARDED_STEP0_RTOL if i == 0 else SHARDED_STEP_RTOL
+        loss_rel = _rel(result["losses"][i], one_chip["losses"][i])
+        gnorm_rel = _rel(result["grad_norms"][i], one_chip["grad_norms"][i])
+        print(f"step {i}: loss 2x2 {result['losses'][i]:.6f} one chip "
+              f"{one_chip['losses'][i]:.6f} rel {loss_rel:.3e}; grad norm "
+              f"2x2 {result['grad_norms'][i]:.6f} one chip "
+              f"{one_chip['grad_norms'][i]:.6f} rel {gnorm_rel:.3e} "
+              f"(bound {bound})")
+        check(max(loss_rel, gnorm_rel) <= bound,
+              f"step {i} of the 2x2 mesh disagrees with one chip")
+        worst = max(worst, loss_rel, gnorm_rel)
+    mesh_final = _host_leaves(result.pop("params"))
+    gap = math.sqrt(sum(float(np.sum(np.square(m - o))) for m, o
+                        in zip(mesh_final, one_chip_final)))
+    step = math.sqrt(sum(float(np.sum(np.square(o - w))) for o, w
+                         in zip(one_chip_final, initial)))
+    update_rel = gap / step
+    print(f"|dW_2x2 - dW_1| / |dW_1| = {gap:.4e} / {step:.4e} = "
+          f"{update_rel:.3e} (bound {SHARDED_UPDATE_RTOL})")
+    check(update_rel <= SHARDED_UPDATE_RTOL,
+          "the 2x2 mesh's parameter update disagrees with one chip")
     print(f"parameter shards on {result['param_devices']} devices, "
           f"{result['split_params']} leaves split")
     check(result["param_devices"] == len(devices),
@@ -266,7 +313,8 @@ def sharded_phase(devices, train_args, reference_args) -> dict:
     print("LEO collectives: " + ", ".join(
         f"{k} {v.wire_bytes:.3e} B" for k, v in sorted(colls.items())))
     check(wire > 0, "LEO counts no collective bytes in the sharded step")
-    return {"loss_rel_diff": rel, "collective_bytes": wire}
+    return {"step_rel_diff": worst, "update_rel_diff": update_rel,
+            "collective_bytes": wire}
 
 
 def main(argv=None) -> int:
@@ -282,16 +330,16 @@ def main(argv=None) -> int:
 
     if args.chips == 4:
         with phase("sharded trainer on 2x2 vs one chip"):
-            sharded_phase(devices, SHARDED_TRAIN_ARGS, (ARCH, False, 4, 1024))
+            sharded_phase(devices)
     else:
         with phase("train at full width"):
-            result = train_phase(TRAIN_ARGS)
+            result = train_phase()
         with phase("device vs CPU forward loss"):
-            reference_phase(ARCH, False, REF_TOKENS)
+            reference_phase()
         with phase("LEO on the compiled step"):
-            leo_phase(result, backend, 1)
+            leo_phase(result, backend)
         with phase("Mosaic kernels vs oracles"):
-            kernels_phase(kernel_cases())
+            kernels_phase()
 
     d = devices[0]
     print(json.dumps({"ok": True, "device": {

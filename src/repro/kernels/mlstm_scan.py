@@ -16,6 +16,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Mosaic contracts f32 operands in one bf16 pass unless asked for full f32.
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, o_ref,
                   c_ref, n_ref, m_ref, *, chunk: int):
@@ -55,14 +58,17 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, o_ref,
     w = jnp.where(causal, jnp.exp(log_w), 0.0)       # (U, T)
 
     qkt = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                              precision=_F32,
                               preferred_element_type=jnp.float32)
     scores = qkt * w
     intra = jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=jnp.float32)
     norm_intra = scores.sum(axis=1, keepdims=True)
 
     d_u = jnp.exp(f_cum + m_prev - m_u)              # (C, 1)
     inter = jax.lax.dot_general(q, c_ref[...], (((1,), (0,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=jnp.float32) * d_u
     norm_inter = jnp.sum(q * n_ref[...], axis=1, keepdims=True) * d_u
     denom = jnp.maximum(jnp.abs(norm_inter + norm_intra), jnp.exp(-m_u))
@@ -73,7 +79,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, o_ref,
     src_w = jnp.exp(li + (f_tot - f_cum) - m_new)    # (C, 1)
     ks = k * src_w
     c_ref[...] = c_ref[...] * carry_decay + jax.lax.dot_general(
-        ks, v, (((0,), (0,)), ((), ())),
+        ks, v, (((0,), (0,)), ((), ())), precision=_F32,
         preferred_element_type=jnp.float32)
     n_ref[...] = n_ref[...] * carry_decay + ks.sum(axis=0, keepdims=True)
     m_ref[...] = m_new

@@ -38,7 +38,7 @@ def _slstm_kernel(xg_ref, r_ref, o_ref, c_ref, n_ref, h_ref, m_ref, *,
 
     def step(t, _):
         xg = xg_ref[0, t].astype(jnp.float32)        # (B, 4D)
-        rec = jax.lax.dot(h_ref[...], r,
+        rec = jax.lax.dot(h_ref[...], r, precision=jax.lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)
         g = xg + rec
         gi, gf = g[:, :d], g[:, d:2 * d]

@@ -124,7 +124,7 @@ def main(argv=None) -> dict:
                     state, shardings=state_sh)
                 print(f"restored from step {start_step}")
 
-        losses, step_seconds = [], []
+        losses, grad_norms, step_seconds = [], [], []
         t0 = time.time()
         it = pipeline(start_step)
         for step in range(start_step, args.steps):
@@ -134,9 +134,10 @@ def main(argv=None) -> dict:
             step_seconds.append(time.perf_counter() - t_step)
             loss = float(metrics["loss"])
             losses.append(loss)
+            grad_norms.append(float(metrics["grad_norm"]))
             if step % 10 == 0 or step == args.steps - 1:
                 print(f"step {step:5d}  loss {loss:.4f}  "
-                      f"gnorm {float(metrics['grad_norm']):.3f}")
+                      f"gnorm {grad_norms[-1]:.3f}")
             if manager and (step + 1) % args.checkpoint_every == 0:
                 manager.save(step + 1, state)
         if manager:
@@ -146,7 +147,8 @@ def main(argv=None) -> dict:
 
         params = jax.tree.leaves(state["params"])
         result = {"final_loss": losses[-1], "first_loss": losses[0],
-                  "losses": losses, "step_seconds": step_seconds,
+                  "losses": losses, "grad_norms": grad_norms,
+                  "step_seconds": step_seconds, "params": state["params"],
                   "steps": args.steps - start_step, "wall_seconds": wall,
                   "param_devices": len({d for p in params
                                         for d in p.sharding.device_set}),
