@@ -1,0 +1,8 @@
+"""Chip-microseconds of leaf device time per trained token in the `optimizer`
+scope (gradient clipping, the schedule and the AdamW update), from the
+trace, attributed by `bench/scopes.py`."""
+from bench import scopes
+
+
+def read(run):
+    return scopes.us_per_token(run, "optimizer")

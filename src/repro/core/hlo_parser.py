@@ -124,11 +124,15 @@ def _take_shape_prefix(rest: str) -> Tuple[str, str]:
     return m.group(1), m.group(2)
 
 
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
+
+
 def _parse_operand_refs(operand_text: str) -> Tuple[str, ...]:
     refs: List[str] = []
     for part in _split_top_level(operand_text):
-        # operand may be '%name' or 'f32[16]{0} %name'
-        toks = part.split()
+        # operand may be '%name', 'f32[16]{0} %name' or, in long lists,
+        # '/*index=5*/%name'
+        toks = _COMMENT_RE.sub(" ", part).split()
         name = None
         for tok in reversed(toks):
             if tok.startswith("%"):

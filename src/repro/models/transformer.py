@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from ..configs.base import ArchConfig
 from . import attention as attn_mod
 from . import moe as moe_mod
+from . import scopes
 from . import ssm as ssm_mod
 from . import xlstm as xlstm_mod
 from .layers import (
@@ -152,37 +153,45 @@ def _block_forward(p: Params, x: jnp.ndarray, desc: Tuple[str, str],
     mixer, ffn = desc
     aux = jnp.zeros((), jnp.float32)
     x = _sp_constraint(x)
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if mixer == "attn":
-        y = attn_mod.attn_forward(p["attn"], h, cfg, positions, chunk)
-    elif mixer == "mla":
-        y = attn_mod.mla_forward(p["attn"], h, cfg, positions, chunk)
-    elif mixer == "ssm":
-        y = ssm_mod.ssm_forward(p["ssm"], h, cfg)
-    elif mixer == "hybrid":
-        y = 0.5 * (attn_mod.attn_forward(p["attn"], h, cfg, positions, chunk)
-                   + ssm_mod.ssm_forward(p["ssm"], h, cfg))
-    elif mixer == "mlstm":
-        y = xlstm_mod.mlstm_forward(p["mlstm"], h, cfg)
-    elif mixer == "slstm":
-        y = xlstm_mod.slstm_forward(p["slstm"], h, cfg)
+    with jax.named_scope(scopes.NORM):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    if mixer == "hybrid":
+        with jax.named_scope(scopes.ATTN):
+            ya = attn_mod.attn_forward(p["attn"], h, cfg, positions, chunk)
+        with jax.named_scope(scopes.SSM):
+            ys = ssm_mod.ssm_forward(p["ssm"], h, cfg)
+        y = 0.5 * (ya + ys)
     else:
-        raise ValueError(mixer)
+        with jax.named_scope(scopes.KIND_SCOPES.get(mixer, mixer)):
+            if mixer == "attn":
+                y = attn_mod.attn_forward(p["attn"], h, cfg, positions, chunk)
+            elif mixer == "mla":
+                y = attn_mod.mla_forward(p["attn"], h, cfg, positions, chunk)
+            elif mixer == "ssm":
+                y = ssm_mod.ssm_forward(p["ssm"], h, cfg)
+            elif mixer == "mlstm":
+                y = xlstm_mod.mlstm_forward(p["mlstm"], h, cfg)
+            elif mixer == "slstm":
+                y = xlstm_mod.slstm_forward(p["slstm"], h, cfg)
+            else:
+                raise ValueError(mixer)
     x = x + y
     if ffn != "none":
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        if ffn == "moe":
-            from .flags import get_flags
-            if get_flags().moe_impl == "ep_shardmap":
-                y, aux = moe_mod.moe_forward_ep(p["ffn"], h, cfg)
+        with jax.named_scope(scopes.NORM):
+            h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+        with jax.named_scope(scopes.KIND_SCOPES[ffn]):
+            if ffn == "moe":
+                from .flags import get_flags
+                if get_flags().moe_impl == "ep_shardmap":
+                    y, aux = moe_mod.moe_forward_ep(p["ffn"], h, cfg)
+                else:
+                    y, aux = moe_mod.moe_forward(p["ffn"], h, cfg)
+                # named for selective remat: saving the MoE output keeps the
+                # backward from re-running dispatch all-to-alls + expert FFNs
+                from jax.ad_checkpoint import checkpoint_name
+                y = checkpoint_name(y, "moe_out")
             else:
-                y, aux = moe_mod.moe_forward(p["ffn"], h, cfg)
-            # named for selective remat: saving the MoE output keeps the
-            # backward from re-running dispatch all-to-alls + expert FFNs
-            from jax.ad_checkpoint import checkpoint_name
-            y = checkpoint_name(y, "moe_out")
-        else:
-            y = mlp(h, p["ffn"])
+                y = mlp(h, p["ffn"])
         x = x + y
     return x, aux
 
@@ -194,10 +203,11 @@ def forward(params: Params, cfg: ArchConfig,
             remat: str = "group") -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Train/prefill forward. Returns (logits (B,S,V) f32, aux_loss)."""
     dtype = _dtype(cfg)
-    if embeds is not None:
-        x = embeds.astype(dtype)
-    else:
-        x = embed(tokens, params["embed"], dtype)
+    with jax.named_scope(scopes.EMBED):
+        if embeds is not None:
+            x = embeds.astype(dtype)
+        else:
+            x = embed(tokens, params["embed"], dtype)
     s = x.shape[1]
     positions = jnp.arange(s)
     aux_total = jnp.zeros((), jnp.float32)
@@ -217,12 +227,13 @@ def forward(params: Params, cfg: ArchConfig,
             body = jax.checkpoint(body)
         (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), stacked)
 
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = unembed(x, params["embed"]["table"], transpose=True)
-    else:
-        logits = unembed(x, params["head"], transpose=False)
-    return logits.astype(jnp.float32), aux_total
+    with jax.named_scope(scopes.HEAD_LOSS):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = unembed(x, params["embed"]["table"], transpose=True)
+        else:
+            logits = unembed(x, params["head"], transpose=False)
+        return logits.astype(jnp.float32), aux_total
 
 
 # -- decode -------------------------------------------------------------------------
@@ -358,7 +369,8 @@ def loss_fn(params: Params, cfg: ArchConfig, batch: Dict,
                           tokens=batch.get("tokens"),
                           embeds=batch.get("embeds"),
                           chunk=chunk, remat=remat)
-    labels = batch["labels"]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return nll.mean() + aux_weight * aux
+    with jax.named_scope(scopes.HEAD_LOSS):
+        labels = batch["labels"]
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        return nll.mean() + aux_weight * aux

@@ -14,14 +14,14 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from ..models import decode_step as model_decode_step
-from ..models import init_decode_state, init_params, loss_fn
+from ..models import init_decode_state, init_params, loss_fn, scopes
 from ..optim import (
     AdamWConfig,
     adamw_init,
@@ -63,6 +63,33 @@ def init_train_state(rng, cfg: ArchConfig) -> Dict[str, Any]:
             "step": jnp.zeros((), jnp.int32)}
 
 
+# The train step traced last in this process, with its arguments' shapes.
+_LAST_TRACED: Optional[Tuple[Callable, Tuple[Any, ...]]] = None
+
+
+def _record_traced(fn: Callable, *args) -> None:
+    """Keep `fn` and the abstract shapes of `args` (no arrays): the body of
+    a jitted step runs only while it is traced."""
+    global _LAST_TRACED
+
+    def abstract(x):
+        aval = jax.typeof(x)
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                    weak_type=aval.weak_type)
+    _LAST_TRACED = (fn, jax.tree.map(abstract, args))
+
+
+def last_traced_train_step() -> Optional[Tuple[Callable, Tuple[Any, ...]]]:
+    """`(train_step, (state, batch))` of the train step traced last in this
+    process, its arguments as `jax.ShapeDtypeStruct`s, or None.
+
+    Lowering `jax.jit(train_step, donate_argnums=(0,))` at those shapes
+    again gives the compiled program that ran, whose instruction names a
+    device profile of the step carries; `repro.core.cct.seconds_by_scope`
+    then attributes the profile's time to the model's named scopes."""
+    return _LAST_TRACED
+
+
 def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig = AdamWConfig(),
                     options: TrainOptions = TrainOptions()):
     def loss_of(params, batch):
@@ -71,6 +98,7 @@ def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig = AdamWConfig(),
 
     def train_step(state: Dict[str, Any], batch: Dict[str, jnp.ndarray]
                    ) -> Tuple[Dict[str, Any], Dict[str, jnp.ndarray]]:
+        _record_traced(train_step, state, batch)
         params = state["params"]
         if options.microbatch > 1:
             def split(x):
@@ -99,13 +127,14 @@ def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig = AdamWConfig(),
         if options.grad_compression:
             ef = state.get("grad_ef")
             grads, new_ef = compress_gradients(grads, ef)
-        grads, gnorm = clip_by_global_norm(grads, options.clip_norm)
-        lr_scale = linear_warmup_cosine(state["step"], options.warmup_steps,
-                                        options.total_steps)
-        new_params, new_opt = adamw_update(opt_cfg, grads, state["opt"],
-                                           params, lr_scale)
-        new_state = {"params": new_params, "opt": new_opt,
-                     "step": state["step"] + 1}
+        with jax.named_scope(scopes.OPTIMIZER):
+            grads, gnorm = clip_by_global_norm(grads, options.clip_norm)
+            lr_scale = linear_warmup_cosine(
+                state["step"], options.warmup_steps, options.total_steps)
+            new_params, new_opt = adamw_update(opt_cfg, grads, state["opt"],
+                                               params, lr_scale)
+            new_state = {"params": new_params, "opt": new_opt,
+                         "step": state["step"] + 1}
         if options.grad_compression:
             new_state["grad_ef"] = new_ef
         metrics = {"loss": loss, "grad_norm": gnorm,

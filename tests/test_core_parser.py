@@ -138,6 +138,32 @@ ENTRY %main (a: bf16[4,512,14,64], b: bf16[4,14,512,512], c: bf16[896,896,1], d:
 """
 
 
+# XLA prints an index comment before every fifth operand of a long list.
+LONG_OPERANDS_HLO = """\
+HloModule long_operands
+
+ENTRY %main (a: f32[4], b: f32[4], c: f32[4], d: f32[4], e: f32[4], f: f32[64]) -> (f32[4], f32[4], f32[4], f32[4], f32[4], f32[64]) {
+  %a = f32[4]{0} parameter(0)
+  %b = f32[4]{0} parameter(1)
+  %c = f32[4]{0} parameter(2)
+  %d = f32[4]{0} parameter(3)
+  %e = f32[4]{0} parameter(4)
+  %f = f32[64]{0} parameter(5)
+  %concatenate.1 = f32[84]{0} concatenate(%a, %b, %c, %d, %e, /*index=5*/%f), dimensions={0}
+  ROOT %tuple.1 = (f32[4], f32[4], f32[4], f32[4], f32[4], /*index=5*/f32[64]) tuple(%a, %b, %c, %d, %e, /*index=5*/%f)
+}
+"""
+
+
+class TestOperandParsing:
+    def test_operands_behind_index_comments_are_kept(self):
+        main = parse_hlo(LONG_OPERANDS_HLO).entry_computation
+        assert main.get("tuple.1").operands == ("a", "b", "c", "d", "e", "f")
+        concat = main.get("concatenate.1")
+        assert concat.operands[-1] == "f"
+        assert concat.bytes_read == (5 * 4 + 64) * 4
+
+
 class TestConvolutionFlops:
     """The TPU compiler's convolutions: each output of a window that the
     padding and lhs dilation leave one real tap costs one multiply-add per
