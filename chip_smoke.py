@@ -167,9 +167,9 @@ def leo_phase(result: dict, backend) -> dict:
 
 def kernel_cases():
     """(name, op, oracle, args builder, atol = rtol) at real model widths:
-    flash at qwen2-0.5b heads, rmsnorm at its d_model, ssm at hymba-1.5b's
-    d_inner and state, mLSTM/sLSTM at xlstm-125m's.  The bf16 kernels get
-    about 2.5 bf16 ulps of 1.0, the f32 ones 1e-4."""
+    flash at qwen2-0.5b heads, rmsnorm at its d_model, the selective scan
+    at hymba-1.5b's d_inner and state, mLSTM/sLSTM at xlstm-125m's.  The
+    bf16 kernels get about 2.5 bf16 ulps of 1.0, the f32 ones 1e-4."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops, ref
@@ -189,8 +189,10 @@ def kernel_cases():
         return rand(3, (s, 896), jnp.bfloat16), 1.0 + 0.1 * rand(4, (896,))
 
     def ssm():
-        return (jax.nn.sigmoid(rand(5, (1, s // 4, din, 16)) + 1.0),
-                rand(6, (1, s // 4, din, 16)), rand(7, (1, s // 4, 16)))
+        return (jax.nn.softplus(rand(5, (1, s // 4, din))),
+                rand(6, (1, s // 4, din)), rand(7, (1, s // 4, 16)),
+                rand(15, (1, s // 4, 16)),
+                -jnp.arange(1.0, 17.0)[None].repeat(din, 0))  # A at init
 
     def mlstm():
         return (rand(8, (2, s // 2, 4, 192)),
@@ -209,8 +211,8 @@ def kernel_cases():
         ("rmsnorm_baseline", ops.rmsnorm_baseline_op, ref.rmsnorm_ref, rms,
          bf16_tol),
         ("rmsnorm_pipelined", ops.rmsnorm_op, ref.rmsnorm_ref, rms, bf16_tol),
-        ("ssm_scan", lambda *a, **k: ops.ssm_scan_op(*a, chunk=64, **k),
-         ref.ssm_scan_ref, ssm, f32_tol),
+        ("selective_scan", ops.selective_scan_op, ref.selective_scan_ref,
+         ssm, f32_tol),
         ("mlstm_chunkwise",
          lambda *a, **k: ops.mlstm_chunkwise_op(*a, chunk=64, **k),
          ref.mlstm_ref, mlstm, f32_tol),

@@ -15,7 +15,7 @@ from .flash_attention import flash_attention
 from .mlstm_scan import mlstm_chunkwise
 from .rmsnorm import rmsnorm_baseline, rmsnorm_pipelined
 from .slstm_scan import slstm_scan
-from .ssm_scan import ssm_scan
+from .ssm_scan import selective_scan
 
 flash_attention_op = jax.jit(
     flash_attention,
@@ -32,13 +32,14 @@ rmsnorm_baseline_op = jax.jit(
 mlstm_chunkwise_op = jax.jit(
     mlstm_chunkwise, static_argnames=("chunk", "interpret"))
 
-ssm_scan_op = jax.jit(ssm_scan, static_argnames=("chunk", "interpret"))
+selective_scan_op = jax.jit(selective_scan,
+                            static_argnames=("chunk", "interpret"))
 
 slstm_scan_op = jax.jit(slstm_scan, static_argnames=("chunk", "interpret"))
 
 __all__ = [
     "flash_attention", "flash_attention_op", "mlstm_chunkwise",
     "mlstm_chunkwise_op", "rmsnorm_baseline", "rmsnorm_baseline_op",
-    "rmsnorm_pipelined", "rmsnorm_op", "slstm_scan", "slstm_scan_op",
-    "ssm_scan", "ssm_scan_op",
+    "rmsnorm_pipelined", "rmsnorm_op", "selective_scan", "selective_scan_op",
+    "slstm_scan", "slstm_scan_op",
 ]

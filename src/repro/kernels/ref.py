@@ -75,19 +75,21 @@ def mlstm_ref(q, k, v, log_i, log_f) -> jnp.ndarray:
     return jnp.moveaxis(ys, 0, 1).astype(q.dtype)
 
 
-def ssm_scan_ref(a, bx, c) -> jnp.ndarray:
-    """Exact sequential h = a*h + bx; y = h . c.  a/bx (B,S,din,N), c (B,S,N)."""
+def selective_scan_ref(dt, x, b_sel, c_sel, a_rate) -> jnp.ndarray:
+    """Exact sequential h_t = exp(A dt_t) * h_{t-1} + dt_t x_t B_t;
+    y_t = C_t . h_t, in f32.  dt/x (B,S,din), b_sel/c_sel (B,S,N),
+    a_rate (din,N)."""
     def step(h, xs):
-        a_t, bx_t, c_t = xs
-        h = a_t.astype(jnp.float32) * h + bx_t.astype(jnp.float32)
-        y = jnp.einsum("bdn,bn->bd", h, c_t.astype(jnp.float32))
-        return h, y
+        dt_t, x_t, b_t, c_t = xs
+        h = jnp.exp(a_rate * dt_t[..., None]) * h + \
+            (dt_t * x_t)[..., None] * b_t[:, None, :]
+        return h, jnp.einsum("bdn,bn->bd", h, c_t)
 
-    b, s, din, n = a.shape
-    h0 = jnp.zeros((b, din, n), jnp.float32)
-    _, ys = jax.lax.scan(step, h0, (jnp.moveaxis(a, 1, 0),
-                                    jnp.moveaxis(bx, 1, 0),
-                                    jnp.moveaxis(c, 1, 0)))
+    b, s, din = dt.shape
+    h0 = jnp.zeros((b, din, a_rate.shape[-1]), jnp.float32)
+    _, ys = jax.lax.scan(step, h0, tuple(
+        jnp.moveaxis(v.astype(jnp.float32), 1, 0)
+        for v in (dt, x, b_sel, c_sel)))
     return jnp.moveaxis(ys, 0, 1)
 
 

@@ -21,6 +21,26 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
+def train_shardings(mesh, cfg, state):
+    """The train state's and the batch's shardings on `mesh`; `state` may
+    be abstract (shapes only)."""
+    from ..parallel.sharding import ShardingRules
+
+    rules = ShardingRules(mesh, cfg)
+    pspecs = rules.param_specs(state["params"])
+    ospecs = rules.opt_specs(state["opt"], state["params"])
+    state_specs = {"params": pspecs, "opt": ospecs, "step": P()}
+    state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs,
+                            is_leaf=lambda x: isinstance(x, P))
+    dp = rules.dp_spec
+    batch_sharding = {
+        "tokens": NamedSharding(mesh, P(dp, None)),
+        "labels": NamedSharding(mesh, P(dp, None)),
+        "embeds": NamedSharding(mesh, P(dp, None, None)),
+    }
+    return state_sh, batch_sharding
+
+
 def build(arch: str, smoke: bool, batch: int, seq: int, mesh,
           microbatch: int = 1, grad_compression: bool = False,
           steps: int = 0, lr: float = 0.0):
@@ -28,29 +48,16 @@ def build(arch: str, smoke: bool, batch: int, seq: int, mesh,
     from ..data.pipeline import DataPipeline
     from ..data.synthetic import SyntheticConfig, SyntheticTokenDataset
     from ..optim import AdamWConfig
-    from ..parallel.sharding import ShardingRules
     from ..runtime.steps import TrainOptions, init_train_state, \
         make_train_step
 
     cfg = get_config(arch)
     if smoke:
         cfg = smoke_config(cfg)
-    rules = ShardingRules(mesh, cfg)
 
     state = init_train_state(jax.random.PRNGKey(0), cfg)
-    pspecs = rules.param_specs(state["params"])
-    ospecs = rules.opt_specs(state["opt"], state["params"])
-    state_specs = {"params": pspecs, "opt": ospecs, "step": P()}
-    state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs,
-                            is_leaf=lambda x: isinstance(x, P))
+    state_sh, batch_sharding = train_shardings(mesh, cfg, state)
     state = jax.tree.map(lambda a, s: jax.device_put(a, s), state, state_sh)
-
-    dp = rules.dp_spec
-    batch_sharding = {
-        "tokens": NamedSharding(mesh, P(dp, None)),
-        "labels": NamedSharding(mesh, P(dp, None)),
-        "embeds": NamedSharding(mesh, P(dp, None, None)),
-    }
     ds = SyntheticTokenDataset(SyntheticConfig(
         vocab_size=cfg.vocab_size, seq_len=seq, d_model=cfg.d_model,
         frontend=cfg.frontend))

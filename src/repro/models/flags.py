@@ -18,6 +18,12 @@ layer:
                                     B x S x d_inner x N in HBM);
                    True           — discretize per chunk inside the scan
                                     (transient, fuses into the chunk body).
+                   Both shape the XLA chunk scan: every CPU process (the
+                   tests, the dry-run, the hillclimb) and every step
+                   sharded over several devices runs it.  A one-device
+                   step in a TPU process scans in the Pallas selective-scan
+                   kernel wherever it applies, and these two flags then
+                   change nothing (`models/ssm.py`).
   moe_impl       : "global"       — routing over the global token axis
                                     (XLA inserts distributed sort/gather
                                     collectives);
@@ -35,7 +41,8 @@ from dataclasses import dataclass, replace
 class ModelFlags:
     attention_impl: str = "xla"
     ssm_fused: bool = False
-    ssm_pallas: bool = False      # cost-model the Pallas ssm_scan kernel
+    ssm_pallas: bool = False      # cost-model the Pallas selective-scan
+                                  # kernel in the XLA chunk scan
     mlstm_pallas: bool = False    # cost-model the Pallas mlstm_chunkwise kernel
     sequence_parallel: bool = False  # shard residual-stream activations over
                                      # "model" between blocks: XLA turns the

@@ -4,10 +4,21 @@ Recurrence per channel c with state size N:
     h_t = exp(dt_t * A) * h_{t-1} + dt_t * B_t * x_t          (N-vector)
     y_t = C_t . h_t + D * x_t
 
-Training runs a `lax.scan` over sequence *chunks* with an associative scan
-inside each chunk (log-depth within chunk, O(S/chunk) sequential steps
-between chunks) — the standard TPU-friendly decomposition.  Decode carries
-`h` as O(1) state, which is what makes `long_500k` feasible for SSM archs.
+Which scan a full-sequence forward runs is chosen in `ssm_forward`:
+
+- In a process whose default backend is a TPU, for a step on one device,
+  with d_inner a multiple of 128 and a kernel chunk that divides S: one
+  Pallas kernel with its own backward (`kernels/ssm_scan.selective_scan`),
+  fed the per-token terms dt, x, B, C; the B x S x d_inner x N terms exist
+  only in VMEM.
+- Everywhere else: a `lax.scan` over sequence *chunks* with an associative
+  scan inside each chunk (`_xla_scan`, shaped by the `ssm_fused` /
+  `ssm_pallas` flags).  That is every CPU process (the tests, LEO's dry-run
+  and hillclimb), and every step sharded over more than one device: XLA
+  cannot partition a Mosaic kernel across a mesh.
+
+Decode carries `h` as O(1) state, which is what makes `long_500k` feasible
+for SSM archs.
 """
 from __future__ import annotations
 
@@ -17,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..kernels.ssm_scan import selective_scan, selective_scan_chunk
 from .flags import get_flags
 from .layers import dense_init, linear
 
@@ -50,13 +62,11 @@ def _discretize(p: Params, xin: jnp.ndarray):
     return a, bx, csel
 
 
-def ssm_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig,
-                chunk: int = 128) -> jnp.ndarray:
-    """x (B, S, D) -> (B, S, D)."""
-    b, s, d = x.shape
-    din = cfg.ssm_expand * d
-    xz = linear(x, p["w_in"])
-    xin, z = xz[..., :din], xz[..., din:]
+def _xla_scan(p: Params, xin: jnp.ndarray, cfg: ArchConfig,
+              chunk: int) -> jnp.ndarray:
+    """The chunked associative scan in XLA ops: xin (B, S, din) -> y
+    (B, S, din) f32, before the D skip and the gate."""
+    b, s, din = xin.shape
     chunk = min(chunk, s)
     assert s % chunk == 0
     nc = s // chunk
@@ -110,7 +120,42 @@ def ssm_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig,
         _, ys = jax.lax.scan(chunk_step, h0,
                              (jnp.moveaxis(a, 1, 0), jnp.moveaxis(bx, 1, 0),
                               jnp.moveaxis(csel, 1, 0)))
-    y = jnp.moveaxis(ys, 0, 1).reshape(b, s, din)
+    return jnp.moveaxis(ys, 0, 1).reshape(b, s, din)
+
+
+def _kernel_scan(p: Params, xin: jnp.ndarray,
+                 interpret: bool = False) -> jnp.ndarray:
+    """The same recurrence as one Pallas kernel with its own backward,
+    fed the per-token terms (kernels/ssm_scan.selective_scan)."""
+    dt = jax.nn.softplus(xin.astype(jnp.float32) * p["w_dt"])
+    bsel = linear(xin, p["w_b"]).astype(jnp.float32)
+    csel = linear(xin, p["w_c"]).astype(jnp.float32)
+    return selective_scan(dt, xin, bsel, csel, -jnp.exp(p["a_log"]),
+                          interpret=interpret)
+
+
+def _scan_in_kernel(xin: jnp.ndarray, cfg: ArchConfig) -> bool:
+    """Whether this forward scans in the Pallas kernel: on a TPU, for a
+    step on one device (by the mesh of `xin`'s sharding and the mesh in
+    context), when the kernel takes the shapes."""
+    _, s, din = xin.shape
+    devices = max(jax.typeof(xin).sharding.mesh.size,
+                  jax.sharding.get_abstract_mesh().size)
+    return (jax.default_backend() == "tpu" and devices <= 1
+            and selective_scan_chunk(s, din, cfg.ssm_state) is not None)
+
+
+def ssm_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig,
+                chunk: int = 128) -> jnp.ndarray:
+    """x (B, S, D) -> (B, S, D).  The scan runs in the Pallas kernel where
+    `_scan_in_kernel` says so, else in `_xla_scan` (module docstring)."""
+    din = cfg.ssm_expand * x.shape[-1]
+    xz = linear(x, p["w_in"])
+    xin, z = xz[..., :din], xz[..., din:]
+    if _scan_in_kernel(xin, cfg):
+        y = _kernel_scan(p, xin)
+    else:
+        y = _xla_scan(p, xin, cfg, chunk)
     y = y + xin.astype(jnp.float32) * p["d_skip"]
     y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
     return linear(y, p["w_out"])

@@ -114,19 +114,138 @@ class TestMlstmKernel:
                                    atol=1e-4, rtol=1e-4)
 
 
-class TestSsmKernel:
-    @pytest.mark.parametrize("s,din,n,chunk", [(32, 128, 8, 8),
-                                               (64, 256, 16, 16)])
-    def test_matches_sequential_ref(self, s, din, n, chunk):
-        ks = jax.random.split(jax.random.PRNGKey(4), 3)
-        b = 2
-        a = jax.nn.sigmoid(_rand(ks[0], (b, s, din, n), jnp.float32) + 1.0)
-        bx = _rand(ks[1], (b, s, din, n), jnp.float32)
-        c = _rand(ks[2], (b, s, n), jnp.float32)
-        out = ops.ssm_scan_op(a, bx, c, chunk=chunk, interpret=True)
-        expect = ref.ssm_scan_ref(a, bx, c)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                                   atol=1e-4, rtol=1e-4)
+def _scan_inputs(b, s, din, n, seed=6):
+    """The model's SSM parameters (f32) and a scanned input."""
+    from repro.configs.base import ArchConfig
+    from repro.models import ssm
+    cfg = ArchConfig(name="scan", family="ssm", n_layers=1,
+                     d_model=din // 2, n_heads=1, n_kv_heads=1, d_ff=0,
+                     vocab_size=8, ssm_state=n, ssm_expand=2)
+    key = jax.random.PRNGKey(seed)
+    p = ssm.init_ssm(key, cfg, jnp.float32)
+    xin = _rand(jax.random.fold_in(key, 1), (b, s, din), jnp.float32)
+    return cfg, p, xin
+
+
+def _scan_terms(p, xin):
+    """dt, x, B, C and A as the model feeds the selective-scan kernel."""
+    from repro.models.layers import linear
+    return (jax.nn.softplus(xin * p["w_dt"]), xin,
+            linear(xin, p["w_b"]), linear(xin, p["w_c"]),
+            -jnp.exp(p["a_log"]))
+
+
+# (b, s, din, n, chunk): several chunks in every case; one, two (1280 =
+# 2 x 640) and three (2304 = 3 x 768) d_inner tiles; chunk None is the
+# kernel's own choice.
+SCAN_CASES = [(2, 64, 256, 16, 16), (2, 32, 1280, 8, 8),
+              (2, 48, 2304, 16, 16), (3, 128, 128, 16, None)]
+
+
+class TestSelectiveScan:
+    @pytest.mark.parametrize("b,s,din,n,chunk", SCAN_CASES)
+    def test_forward_matches_xla_path_and_sequential_ref(self, b, s, din, n,
+                                                         chunk):
+        from repro.models import ssm
+        cfg, p, xin = _scan_inputs(b, s, din, n)
+        terms = _scan_terms(p, xin)
+        with jax.default_matmul_precision("highest"):
+            out = ops.selective_scan_op(*terms, chunk=chunk, interpret=True)
+            xla = ssm._xla_scan(p, xin, cfg, chunk=16)
+            seq = ref.selective_scan_ref(*terms)
+        for expect in (xla, seq):
+            np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                                       atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize("b,s,din,n,chunk", SCAN_CASES)
+    def test_grad_matches_xla_path(self, b, s, din, n, chunk):
+        """Through the custom VJP, the gradient in dt, x, B, C and A is
+        the one autodiff of the plain recurrence gives."""
+        cfg, p, xin = _scan_inputs(b, s, din, n)
+        terms = _scan_terms(p, xin)
+        w = _rand(jax.random.PRNGKey(9), (b, s, din), jnp.float32)
+        args = tuple(range(5))
+
+        def loss(scan):
+            return lambda *t: jnp.sum(scan(*t) * w)
+        with jax.default_matmul_precision("highest"):
+            got = jax.grad(loss(lambda *t: ops.selective_scan_op(
+                *t, chunk=chunk, interpret=True)), args)(*terms)
+            want = jax.grad(loss(ref.selective_scan_ref), args)(*terms)
+        for name, g, e in zip(("dt", "x", "B", "C", "A"), got, want):
+            scale = float(jnp.max(jnp.abs(e)))
+            np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                       atol=1e-4 * scale, rtol=1e-4,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("b,s,din,n,chunk", SCAN_CASES[:2])
+    def test_model_grads_match_xla_path(self, b, s, din, n, chunk):
+        """The SSM's parameters and input get the same gradient through
+        the kernel as through the model's XLA chunk scan."""
+        from repro.models import ssm
+        cfg, p, xin = _scan_inputs(b, s, din, n)
+        w = _rand(jax.random.PRNGKey(9), (b, s, din), jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            got = jax.grad(lambda p, x: jnp.sum(ssm._kernel_scan(
+                p, x, interpret=True) * w), (0, 1))(p, xin)
+            want = jax.grad(lambda p, x: jnp.sum(ssm._xla_scan(
+                p, x, cfg, chunk=16) * w), (0, 1))(p, xin)
+        flat_got = jax.tree_util.tree_leaves_with_path(got)
+        flat_want = jax.tree_util.tree_leaves(want)
+        for (path, g), e in zip(flat_got, flat_want):
+            scale = float(jnp.max(jnp.abs(e))) or 1.0
+            np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                       atol=1e-4 * scale, rtol=1e-4,
+                                       err_msg=jax.tree_util.keystr(path))
+
+    @pytest.mark.parametrize("s,din,n,chunk", [
+        (4096, 3200, 16, 64),   # hymba-1.5b: the backward's VMEM bounds it
+        (256, 128, 8, 256),
+        (96, 256, 16, 32),
+        (100, 256, 16, None),   # no chunk divides the sequence
+        (256, 200, 16, None),   # d_inner is not lane-aligned
+    ])
+    def test_chunk_follows_shapes_and_vmem(self, s, din, n, chunk):
+        from repro.kernels.ssm_scan import selective_scan_chunk
+        assert selective_scan_chunk(s, din, n) == chunk
+
+
+class TestScanPathByPlatform:
+    def test_cpu_lowers_hymba_step_without_the_kernel(self):
+        """On the CPU backend the hymba-width step holds no Pallas call."""
+        import dataclasses
+        from repro.configs.lm_archs import HYMBA_1_5B
+        from repro.runtime.steps import init_train_state, make_train_step
+        cfg = dataclasses.replace(HYMBA_1_5B, n_layers=1)
+        state = jax.eval_shape(lambda: init_train_state(
+            jax.random.PRNGKey(0), cfg))
+        batch = {k: jax.ShapeDtypeStruct((1, 1024), jnp.int32)
+                 for k in ("tokens", "labels")}
+        text = jax.jit(make_train_step(cfg)).lower(state, batch).as_text()
+        assert "tpu_custom_call" not in text
+        assert "selective_scan" not in text
+
+    @pytest.mark.parametrize("backend,devices,s,din,kernel", [
+        ("tpu", 1, 256, 256, True),
+        ("cpu", 1, 256, 256, False),    # the CPU tests, dry-run, hillclimb
+        ("tpu", 4, 256, 256, False),    # a sharded step: no partitioning
+        ("tpu", 1, 100, 256, False),    # no kernel chunk divides S
+        ("tpu", 1, 256, 200, False),    # d_inner is not lane-aligned
+    ])
+    def test_scan_path_follows_backend_devices_and_shapes(
+            self, monkeypatch, backend, devices, s, din, kernel):
+        from jax.sharding import AbstractMesh, AxisType
+        from repro.models import ssm
+        cfg, _, _ = _scan_inputs(1, 8, 256, 16)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        mesh = AbstractMesh((devices, 1), ("data", "model"),
+                            axis_types=(AxisType.Auto,) * 2)
+        xin = jax.ShapeDtypeStruct((1, s, din), jnp.bfloat16)
+        with jax.sharding.use_abstract_mesh(mesh):
+            chosen = []
+            jax.jit(lambda x: chosen.append(ssm._scan_in_kernel(x, cfg))
+                    ).trace(xin)
+        assert chosen == [kernel]
 
 
 class TestSlstmKernel:

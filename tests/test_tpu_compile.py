@@ -10,6 +10,7 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and pytest
 workers import every test file.
 """
+import dataclasses
 import os
 
 import jax
@@ -22,10 +23,10 @@ from repro.kernels import ops
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One device of a described v5e:2x2, with the persistent compilation
-    cache off: a program compiled for a described chip is written to it
-    but cannot be read back without one."""
+def v5e_2x2():
+    """A described v5e:2x2, with the persistent compilation cache off: a
+    program compiled for a described chip is written to it but cannot be
+    read back without one."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -40,13 +41,19 @@ def one_chip():
                                                 topology_name="v5e:2x2")
         except Exception as e:  # noqa: BLE001 - any failure means no TPU lib
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_on)
         if saved_log_dir is None:
             os.environ.pop("TPU_LOG_DIR", None)
         else:
             os.environ["TPU_LOG_DIR"] = saved_log_dir
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    """One device of the described v5e:2x2."""
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, shapes, sharding):
@@ -57,8 +64,20 @@ def _compile(fn, shapes, sharding):
 BF16, F32 = jnp.bfloat16, jnp.float32
 
 # Each kernel at a real model width: flash at qwen2-0.5b's heads (14 q / 2
-# kv, head_dim 64), rmsnorm at its d_model, ssm at hymba-1.5b's d_inner 3200
-# and state 16, mLSTM (4 heads of 192) and sLSTM (d 768) at xlstm-125m's.
+# kv, head_dim 64), rmsnorm at its d_model, the selective scan at
+# hymba-1.5b's d_inner 3200 and state 16 over its 4096-token rows (the
+# backward with the forward it differentiates), mLSTM (4 heads of 192) and
+# sLSTM (d 768) at xlstm-125m's.
+SELECTIVE_SCAN_ARGS = [((1, 4096, 3200), F32), ((1, 4096, 3200), BF16),
+                       ((1, 4096, 16), F32), ((1, 4096, 16), F32),
+                       ((3200, 16), F32)]
+
+
+def _selective_scan_grad(*args):
+    loss = lambda *a: ops.selective_scan_op(*a, interpret=False).sum()
+    return jax.grad(loss, argnums=tuple(range(len(args))))(*args)
+
+
 KERNELS = {
     "flash_attention": (
         lambda q, k, v: ops.flash_attention_op(q, k, v, interpret=False),
@@ -70,11 +89,10 @@ KERNELS = {
     "rmsnorm_pipelined": (
         lambda x, s: ops.rmsnorm_op(x, s, interpret=False),
         [((1024, 896), BF16), ((896,), F32)]),
-    "ssm_scan": (
-        lambda a, bx, c: ops.ssm_scan_op(a, bx, c, chunk=64,
-                                         interpret=False),
-        [((1, 256, 3200, 16), F32), ((1, 256, 3200, 16), F32),
-         ((1, 256, 16), F32)]),
+    "selective_scan_fwd": (
+        lambda *a: ops.selective_scan_op(*a, interpret=False),
+        SELECTIVE_SCAN_ARGS),
+    "selective_scan_bwd": (_selective_scan_grad, SELECTIVE_SCAN_ARGS),
     "mlstm_chunkwise": (
         lambda q, k, v, i, f: ops.mlstm_chunkwise_op(q, k, v, i, f, chunk=64,
                                                      interpret=False),
@@ -90,6 +108,92 @@ def test_kernel_compiles_with_mosaic(name, one_chip):
     fn, shapes = KERNELS[name]
     compiled = _compile(fn, shapes, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _hymba_step(layers: int, batch: int, seq_len: int, shardings):
+    """hymba-1.5b's train step at its widths, cut to `layers` layers, and
+    its arguments' shapes, placed by `shardings(cfg, state)`, which gives
+    the state's and the batch's shardings."""
+    from repro.configs.lm_archs import HYMBA_1_5B
+    from repro.runtime.steps import init_train_state, make_train_step
+    cfg = dataclasses.replace(HYMBA_1_5B, n_layers=layers)
+    state = jax.eval_shape(lambda: init_train_state(jax.random.PRNGKey(0),
+                                                    cfg))
+    batch = {k: jax.ShapeDtypeStruct((batch, seq_len), jnp.int32)
+             for k in ("tokens", "labels")}
+    state_sh, batch_sh = shardings(cfg, state)
+    place = lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+    args = (jax.tree.map(place, state, state_sh),
+            {k: place(v, batch_sh[k]) for k, v in batch.items()})
+    return jax.jit(make_train_step(cfg), donate_argnums=(0,)), args
+
+
+def _on(sharding):
+    return lambda cfg, state: (jax.tree.map(lambda _: sharding, state),
+                               {"tokens": sharding, "labels": sharding})
+
+
+def _kernels(module):
+    return [i for i in module.all_instructions()
+            if i.attributes.get("custom_call_target", "").strip('"')
+            == "tpu_custom_call"]
+
+
+def _ssm_carries(text, d_inner=3200):
+    """The chunk scans' `while` loops over an f32[batch, d_inner, state]
+    carry (hymba-1.5b's state is 16)."""
+    return [line for line in text.splitlines()
+            if " while(" in line and f",{d_inner},16]" in line]
+
+
+def test_hymba_step_scans_ssm_in_the_kernel(one_chip, monkeypatch):
+    """On one chip, in a process whose default backend is the TPU, each
+    layer's SSM scan is the selective-scan kernel pair, inside the model's
+    `ssm` scope, and no chunk scan over an f32[batch, d_inner, state]
+    carry is left."""
+    from repro.core.cct import InstructionScopes
+    from repro.models.scopes import MODEL_SCOPES
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args = _hymba_step(1, 1, 256, _on(one_chip))
+    text = step.lower(*args).compile().as_text()
+    module = parse_hlo(text)
+    kernels = _kernels(module)
+    names = sorted({i.name.rsplit(".", 1)[0] for i in kernels})
+    assert names == ["selective_scan_bwd", "selective_scan_fwd"], names
+    scope = InstructionScopes(module, MODEL_SCOPES)
+    assert {scope(i) for i in kernels} == {"ssm"}
+    assert not _ssm_carries(text)
+
+
+def test_kernel_scan_cuts_hymba_step_temporaries(one_chip, monkeypatch):
+    """At hymba-1.5b's 4096-token row, one layer, the compiler plans under
+    0.4x the temporaries for the kernel scan that it plans for the XLA
+    chunk scan (3.80 GB against 13.03 GB when written): the kernel never
+    holds the B x S x d_inner x N terms in HBM."""
+    temps = {}
+    for backend in ("tpu", "cpu"):   # the kernel scan, then the XLA scan
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        step, args = _hymba_step(1, 1, 4096, _on(one_chip))
+        temps[backend] = step.lower(*args).compile().memory_analysis(
+            ).temp_size_in_bytes
+    assert temps["tpu"] < 0.4 * temps["cpu"], temps
+
+
+@pytest.mark.parametrize("model_parallel", [1, 2])
+def test_sharded_hymba_step_keeps_the_xla_scan(model_parallel, v5e_2x2,
+                                               monkeypatch):
+    """With `launch/train.py`'s shardings over the whole v5e:2x2, the step
+    compiles: it scans in XLA, since a Mosaic kernel cannot be partitioned
+    across devices."""
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import train_shardings
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_host_mesh(model_parallel, devices=v5e_2x2.devices)
+    step, args = _hymba_step(
+        1, 4, 256, lambda cfg, state: train_shardings(mesh, cfg, state))
+    text = step.lower(*args).compile().as_text()
+    assert not _kernels(parse_hlo(text))
+    assert _ssm_carries(text, 3200 // model_parallel)
 
 
 def _attention_grad(q, k):
