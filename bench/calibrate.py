@@ -13,7 +13,8 @@ comparison's numbers (`bench/compare.py`) of
              program's place (on the first CONTROL_SEEDS seeds);
   half_batch the program trained on half of each batch, the mean taken over
              the rest: a fault the comparison has to catch (on the first
-             CONTROL_SEEDS seeds).
+             CONTROL_SEEDS seeds).  Half of the rows, or of the positions
+             where the rows left would not divide over the mesh's `data`.
 
 A step that returns its state unchanged reads 1 on both leaf numbers by
 their definition and needs no run.  One JSON line per reading goes to
@@ -36,42 +37,55 @@ ROOT = os.path.dirname(BENCH)
 CONTROL_SEEDS = 3  # seeds that the control and the fault are read on
 
 
-def half(batch: dict) -> dict:
-    """Half of the batch's rows, or of its positions where it has one row."""
+def half(batch: dict, data: int = 1) -> dict:
+    """Half of the batch's rows, while the rows left still divide over a
+    mesh's `data` axis of that size; otherwise half of its positions."""
     b, s = batch["tokens"].shape
-    if b > 1:
+    if b // 2 and (b // 2) % data == 0:
         return {k: v[: b // 2] for k, v in batch.items()}
     return {k: v[:, : s // 2] for k, v in batch.items()}
 
 
-def program_readings(spec, seed: int, fault=None):
+def half_placed(spec):
+    """`half` for the cell's batches, each half laid out as its batch was."""
+    import jax
+    data = spec["traffic"].get("mesh", {}).get("data", 1)
+    return lambda batch: {k: jax.device_put(v, batch[k].sharding)
+                          for k, v in half(batch, data).items()}
+
+
+def program_readings(spec, seed: int, fault=None, devices=None):
+    """The program's readings, on `devices` where the traffic has a mesh."""
     from bench.drivers import train
-    _, state, batches, readings, param_key = train.checked_start(
-        spec["config"], spec["traffic"], seed, fault)
+    _, state, batches, readings, param_key, _ = train.checked_start(
+        spec["config"], spec["traffic"], seed, fault, devices)
     del state
     gc.collect()
     return readings, param_key, batches
 
 
-def reference_readings(spec, param_key, batches, precision="f32"):
+def reference_readings(spec, param_key, batches, precision="f32",
+                       devices=None):
     from bench.drivers import train
     out = train.reference_readings(spec["config"], spec["traffic"], param_key,
-                                   batches, precision)
+                                   batches, precision, devices)
     gc.collect()
     return out
 
 
-def calibrate(spec, seeds, emit=print):
+def calibrate(spec, seeds, emit=print, devices=None):
     from bench import compare
     kinds = {"program": [], "control": [], "half_batch": []}
     for i, seed in enumerate(seeds):
-        prog, param_key, batches = program_readings(spec, seed)
-        ref = reference_readings(spec, param_key, batches)
+        prog, param_key, batches = program_readings(spec, seed,
+                                                    devices=devices)
+        ref = reference_readings(spec, param_key, batches, devices=devices)
         found = {"program": prog}
         if i < CONTROL_SEEDS:
             found["control"] = reference_readings(spec, param_key, batches,
-                                                  "fp8")
-            found["half_batch"] = program_readings(spec, seed, half)[0]
+                                                  "fp8", devices)
+            found["half_batch"] = program_readings(
+                spec, seed, half_placed(spec), devices)[0]
         for kind, readings in found.items():
             gaps = compare.gaps(readings, ref)
             kinds[kind].append(gaps)
@@ -138,10 +152,11 @@ def main(argv=None) -> int:
     sys.path[:1] = [ROOT, os.path.join(ROOT, "src")]
     from bench import run
     spec = run.cell_spec(args.workload)
-    run.device_gate(spec["cell"]["chips"])
+    devices = run.device_gate(spec["cell"]["chips"])
     run.enable_compile_cache()
     summary = calibrate(spec, args.seeds,
-                        emit=lambda line: print(line, flush=True))
+                        emit=lambda line: print(line, flush=True),
+                        devices=devices)
     summary["seeds"] = {"program": args.seeds,
                         "control": args.seeds[:CONTROL_SEEDS]}
     if args.write:

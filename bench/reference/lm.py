@@ -12,9 +12,12 @@ copy, global-norm clipping and linear-warmup cosine decay.
 
 Everything here is computed in float32 at `highest` matmul precision, one
 layer and one block of rows at a time so that it fits beside nothing else
-on one chip.  `precision="fp8"` computes every matrix product on float8
-operands, one scale per tensor: e4m3 forward, e5m2 for the cotangents.
-That is the control, which has to come out as not correct.
+on one chip.  Given several devices, it lays its state and its batches over
+all of them, each array split along its largest axis that divides evenly,
+and lets `jax.jit` partition the same code.  `precision="fp8"` computes
+every matrix product on float8 operands, one scale per tensor: e4m3
+forward, e5m2 for the cotangents.  That is the control, which has to come
+out as not correct.
 """
 from __future__ import annotations
 
@@ -23,6 +26,8 @@ from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -250,10 +255,11 @@ def init_state(key, cfg: dict) -> Dict:
             "count": jnp.zeros((), jnp.int32)}
 
 
-def make_step(cfg: dict, dtypes, precision: str = "f32"):
+def make_step(cfg: dict, dtypes, precision: str = "f32", layout=None):
     """(state, tokens, labels, lr) -> (state, loss, pre-clip grad norm,
     clipped gradient).  The forward reads the master copy rounded to the
-    served dtype of each leaf."""
+    served dtype of each leaf.  `layout`, a tree of shardings like the
+    state, keeps the state and the gradient laid out as the state is."""
     a = ADAM
 
     def step(state, tokens, labels, lr):
@@ -280,20 +286,46 @@ def make_step(cfg: dict, dtypes, precision: str = "f32"):
         new = {"master": master, "mu": mu, "nu": nu, "count": count}
         return new, value, gnorm, grads
 
-    return jax.jit(step, donate_argnums=(0,))
+    if layout is None:
+        return jax.jit(step, donate_argnums=(0,))
+    return jax.jit(step, donate_argnums=(0,),
+                   out_shardings=(layout, None, None, layout["master"]))
+
+
+def spread(shape, mesh: Mesh) -> NamedSharding:
+    """An array of `shape` split over every device of the one-axis `mesh`
+    along its largest axis that divides evenly; replicated where none
+    does."""
+    even = [i for i, n in enumerate(shape) if n % mesh.size == 0]
+    spec = [None] * len(shape)
+    if even:
+        spec[max(even, key=lambda i: shape[i])] = mesh.axis_names[0]
+    return NamedSharding(mesh, PartitionSpec(*spec))
 
 
 def train(cfg: dict, key, batches: List[Tuple], norms,
-          precision: str = "f32") -> Dict:
+          precision: str = "f32", devices=None) -> Dict:
     """The first len(batches) steps from the seed's parameters: each step's
     loss and pre-clip gradient norm, and `norms` (a traceable function of a
     tree) of the first clipped gradient and of the master weights' change,
-    as the device arrays it returns."""
+    as the device arrays it returns.  On more than one of `devices` the
+    state and the batches are laid out over all of them (`spread`)."""
     with jax.default_matmul_precision("highest"):
-        state = jax.jit(lambda k: init_state(k, cfg))(key)
         dtypes = jax.tree.map(lambda p: p.dtype,
                               jax.eval_shape(lambda: init_params(key, cfg)))
-        step = make_step(cfg, dtypes, precision)
+        if devices is None or len(devices) == 1:
+            layout = None
+            state = jax.jit(lambda k: init_state(k, cfg))(key)
+        else:
+            mesh = Mesh(np.array(devices), ("devices",))
+            layout = jax.tree.map(
+                lambda a: spread(a.shape, mesh),
+                jax.eval_shape(lambda: init_state(key, cfg)))
+            state = jax.jit(lambda k: init_state(k, cfg),
+                            out_shardings=layout)(key)
+            batches = [tuple(jax.device_put(a, spread(a.shape, mesh))
+                             for a in batch) for batch in batches]
+        step = make_step(cfg, dtypes, precision, layout)
         losses, gnorms, first_grad = [], [], None
         for i, (tokens, labels) in enumerate(batches):
             state, value, gnorm, grads = step(state, tokens, labels,
@@ -303,8 +335,14 @@ def train(cfg: dict, key, batches: List[Tuple], norms,
             if first_grad is None:
                 first_grad = jax.jit(norms)(grads)
             del grads
+
+        def params_of(k):
+            params = init_params(k, cfg)
+            if layout is None:
+                return params
+            return jax.lax.with_sharding_constraint(params, layout["master"])
         change = jax.jit(lambda k, end: norms(jax.tree.map(
-            lambda e, p: e - p.astype(F32), end, init_params(k, cfg))))
+            lambda e, p: e - p.astype(F32), end, params_of(k))))
         change_norms = change(key, state["master"])
         return {"losses": [float(x) for x in losses],
                 "grad_norms": [float(x) for x in gnorms],

@@ -7,10 +7,11 @@
 Everything is found by name.  The cell is an entry of `workloads` in
 `BENCHMARK.json`; it names a configuration, `bench/configs/<config>.json`,
 and a traffic mix, `bench/traffic/<traffic>.json`, whose `kind` names the
-driver `bench/drivers/<kind>.py`.  The limits of its comparison are in
-`bench/limits/<cell>.json`, each per-layer metric is read by
-`bench/metrics/<metric>.py`, and peaks are looked up in `bench/peaks.json`
-by the device's kind.
+driver `bench/drivers/<kind>.py`; a traffic with a `mesh` (`{"data": d,
+"model": m}`) lays the program over the cell's d x m chips.  The limits of
+its comparison are in `bench/limits/<cell>.json`, each per-layer metric is
+read by `bench/metrics/<metric>.py`, and peaks are looked up in
+`bench/peaks.json` by the device's kind.
 
 With `--trace 0` the run reports the cell's end-to-end metrics; with
 `--trace 1` it records a profiler trace of the window and reports its
@@ -57,9 +58,15 @@ def cell_spec(workload: str) -> dict:
     def applies(metric):
         return workload in metric.get("workloads", [workload])
 
+    traffic = load_json(BENCH, "traffic", cell["traffic"] + ".json")
+    mesh = traffic.get("mesh")
+    if mesh and mesh["data"] * mesh["model"] != cell["chips"]:
+        raise ValueError(f"{workload}: traffic {cell['traffic']!r} lays the "
+                         f"program over a {mesh['data']} x {mesh['model']} "
+                         f"mesh, the cell asks for {cell['chips']} chips")
     return {"cell": cell,
             "config": load_json(BENCH, "configs", cell["config"] + ".json"),
-            "traffic": load_json(BENCH, "traffic", cell["traffic"] + ".json"),
+            "traffic": traffic,
             "limits": load_json(BENCH, "limits", workload + ".json"),
             "end_to_end": [m for m in spec["end_to_end"] if applies(m)],
             "per_layer": [m for m in spec["per_layer"] if applies(m)]}
@@ -145,7 +152,7 @@ def execute(spec: dict, seed: int, seconds: float, trace: bool,
 
     try:
         out = driver.run(spec["config"], spec["traffic"], seed, seconds,
-                         traced, peak_memory)
+                         traced, peak_memory, devices)
         reduced = None
         if trace:
             from bench import trace as trace_mod

@@ -37,8 +37,8 @@ def _half_batch(step):
 def test_a_broken_step_makes_the_run_incorrect(monkeypatch, fault, config):
     build_step = train.build_step
 
-    def broken_build(arch, traffic):
-        step = build_step(arch, traffic)
+    def broken_build(*args):
+        step = build_step(*args)
         return jax.jit(fault(step.__wrapped__ if hasattr(step, "__wrapped__")
                              else step))
 
