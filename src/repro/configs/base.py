@@ -30,6 +30,8 @@ class ArchConfig:
     window: int = 4096          # sliding-window size (attention == "swa")
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    partial_rotary_factor: float = 1.0  # share of each head's dims rotated
+    rope_interleave: bool = False  # rotate pairs (2i, 2i+1), not (i, i + r/2)
     mlp_kind: str = "swiglu"    # swiglu | gelu
 
     # MLA (deepseek-v2)
@@ -66,6 +68,11 @@ class ArchConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of each query and key head that rotary rotates."""
+        return int(self.head_dim_ * self.partial_rotary_factor)
 
     @property
     def is_recurrent(self) -> bool:

@@ -36,7 +36,12 @@ GLM4_9B = ArchConfig(
     name="glm4-9b", family="dense",
     n_layers=40, d_model=4096, n_heads=32, n_kv_heads=2, d_ff=13696,
     vocab_size=151552, head_dim=128, rope_theta=1e4,
-    source="RoPE, GQA [hf:THUDM/glm-4-9b; hf]",
+    # Rotary on the first half of each head (rotary_dim = kv_channels / 2),
+    # on adjacent pairs; QKV bias; layernorm_epsilon; untied output_layer.
+    partial_rotary_factor=0.5, rope_interleave=True, qkv_bias=True,
+    norm_eps=1.5625e-7, tie_embeddings=False,
+    source="partial interleaved RoPE, GQA, QKV bias "
+           "[arXiv:2406.12793; hf:THUDM/glm-4-9b config.json]",
 )
 
 DEEPSEEK_CODER_33B = ArchConfig(

@@ -11,6 +11,8 @@ the chip it ran on, so it needs a chip that backend table lists.  Example:
       --steps 50 --batch 8 --seq 64 --checkpoint-dir ckpt
   python -m repro.launch.train --arch qwen2-0.5b --steps 10 --batch 4 \
       --seq 1024 --analyze            # on a TPU, PYTHONPATH=src
+  python -m repro.launch.train --arch glm4-9b --layers 6 --steps 10 \
+      --batch 2 --seq 4096 --model-parallel 2   # a 2x2 v5e host
 """
 from __future__ import annotations
 
@@ -43,7 +45,11 @@ def train_shardings(mesh, cfg, state):
 
 def build(arch: str, smoke: bool, batch: int, seq: int, mesh,
           microbatch: int = 1, grad_compression: bool = False,
-          steps: int = 0, lr: float = 0.0):
+          steps: int = 0, lr: float = 0.0, layers: int = 0):
+    """`layers`, where given, trains that many of the architecture's
+    layers: a pipeline stage's share of a model the mesh cannot hold."""
+    from dataclasses import replace
+
     from ..configs import get_config, smoke_config
     from ..data.pipeline import DataPipeline
     from ..data.synthetic import SyntheticConfig, SyntheticTokenDataset
@@ -54,10 +60,15 @@ def build(arch: str, smoke: bool, batch: int, seq: int, mesh,
     cfg = get_config(arch)
     if smoke:
         cfg = smoke_config(cfg)
+    if layers:
+        cfg = replace(cfg, n_layers=layers)
 
-    state = init_train_state(jax.random.PRNGKey(0), cfg)
-    state_sh, batch_sharding = train_shardings(mesh, cfg, state)
-    state = jax.tree.map(lambda a, s: jax.device_put(a, s), state, state_sh)
+    # Drawn on the mesh, each device making its own shards: a model that no
+    # one device holds never passes through one.
+    init = lambda key: init_train_state(key, cfg)  # noqa: E731
+    state_sh, batch_sharding = train_shardings(
+        mesh, cfg, jax.eval_shape(init, jax.random.PRNGKey(0)))
+    state = jax.jit(init, out_shardings=state_sh)(jax.random.PRNGKey(0))
     ds = SyntheticTokenDataset(SyntheticConfig(
         vocab_size=cfg.vocab_size, seq_len=seq, d_model=cfg.d_model,
         frontend=cfg.frontend))
@@ -102,6 +113,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--checkpoint-every", type=int, default=25)
     ap.add_argument("--restore", action="store_true")
     ap.add_argument("--model-parallel", type=int, default=1)
+    ap.add_argument("--layers", type=int, default=0,
+                    help="train this many of the architecture's layers "
+                         "(0 = all)")
     ap.add_argument("--analyze", action="store_true",
                     help="run LEO on the compiled train step")
     args = ap.parse_args(argv)
@@ -119,7 +133,7 @@ def main(argv=None) -> dict:
             args.arch, args.smoke, args.batch, args.seq, mesh,
             microbatch=args.microbatch,
             grad_compression=args.grad_compression,
-            steps=args.steps, lr=args.lr)
+            steps=args.steps, lr=args.lr, layers=args.layers)
 
         manager = None
         start_step = 0
@@ -155,7 +169,8 @@ def main(argv=None) -> dict:
         params = jax.tree.leaves(state["params"])
         result = {"final_loss": losses[-1], "first_loss": losses[0],
                   "losses": losses, "grad_norms": grad_norms,
-                  "step_seconds": step_seconds, "params": state["params"],
+                  "step_seconds": step_seconds,
+                  "master": state["opt"]["master"],
                   "steps": args.steps - start_step, "wall_seconds": wall,
                   "param_devices": len({d for p in params
                                         for d in p.sharding.device_set}),

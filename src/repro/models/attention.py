@@ -156,9 +156,9 @@ def attn_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig,
     q = linear(x, p["wq"], p.get("bq")).reshape(b, s, h, hd)
     k = linear(x, p["wk"], p.get("bk")).reshape(b, s, kv, hd)
     v = linear(x, p["wv"], p.get("bv")).reshape(b, s, kv, hd)
-    cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    cos, sin = rope_cos_sin(positions, cfg.rotary_dim, cfg.rope_theta)
+    q = apply_rope(q, cos, sin, cfg.rope_interleave)
+    k = apply_rope(k, cos, sin, cfg.rope_interleave)
     window = cfg.window if cfg.attention == "swa" else None
     out = chunked_attention(q, k, v, chunk=chunk, window=window)
     return linear(out.reshape(b, s, h * hd), p["wo"])
@@ -189,9 +189,9 @@ def attn_decode(p: Params, x: jnp.ndarray, cache: Params, pos: jnp.ndarray,
     q = linear(x, p["wq"], p.get("bq")).reshape(b, h, hd)
     k = linear(x, p["wk"], p.get("bk")).reshape(b, kv, hd)
     v = linear(x, p["wv"], p.get("bv")).reshape(b, kv, hd)
-    cos, sin = rope_cos_sin(pos[None], hd, cfg.rope_theta)
-    q = apply_rope(q[:, None], cos, sin)[:, 0]
-    k = apply_rope(k[:, None], cos, sin)[:, 0]
+    cos, sin = rope_cos_sin(pos[None], cfg.rotary_dim, cfg.rope_theta)
+    q = apply_rope(q[:, None], cos, sin, cfg.rope_interleave)[:, 0]
+    k = apply_rope(k[:, None], cos, sin, cfg.rope_interleave)[:, 0]
 
     stacked = layer_idx is not None
     cache_len = cache["k"].shape[2 if stacked else 1]

@@ -31,24 +31,39 @@ def linear(x: jnp.ndarray, w: jnp.ndarray,
 
 # -- RoPE --------------------------------------------------------------------
 
-def rope_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float):
-    """positions (...,) -> cos/sin (..., head_dim//2), f32."""
-    half = head_dim // 2
+def rope_cos_sin(positions: jnp.ndarray, rotary_dim: int, theta: float):
+    """positions (...,) -> cos/sin (..., rotary_dim//2), f32: pair i turns
+    at theta ** (-2i / rotary_dim)."""
+    half = rotary_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):
-    """x (..., n_heads, head_dim); cos/sin broadcastable (..., head_dim//2)."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
+def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
+               interleave: bool = False):
+    """x (..., n_heads, head_dim); cos/sin broadcastable (..., r//2).
+
+    Rotates the first r dims of each head and passes the rest through.
+    Pair i is dims (i, i + r/2) (half-split), or (2i, 2i + 1) with
+    `interleave` (GLM's adjacent pairs)."""
+    rot = 2 * cos.shape[-1]
+    xr = x if rot == x.shape[-1] else x[..., :rot]
+    if interleave:
+        pairs = xr.reshape(*xr.shape[:-1], rot // 2, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = xr[..., :rot // 2], xr[..., rot // 2:]
     cos = cos[..., None, :].astype(jnp.float32)
     sin = sin[..., None, :].astype(jnp.float32)
     x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate(
-        [x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1
-    ).astype(x.dtype)
+    y1, y2 = x1f * cos - x2f * sin, x2f * cos + x1f * sin
+    if interleave:
+        out = jnp.stack([y1, y2], axis=-1).reshape(xr.shape)
+    else:
+        out = jnp.concatenate([y1, y2], axis=-1)
+    out = out.astype(x.dtype)
+    return out if xr is x else jnp.concatenate([out, x[..., rot:]], axis=-1)
 
 
 # -- MLPs ---------------------------------------------------------------------
