@@ -16,7 +16,9 @@ With no option it drives, in one process:
      estimate beside the measured step, the model-FLOP share of LEO's
      counted FLOPs (`useful_ratio`), and every registered backend;
   5. each Pallas kernel compiled by Mosaic at a real model width and
-     checked against its oracle in `repro.kernels.ref`.
+     checked against its oracle in `repro.kernels.ref`; the flash
+     attention pair also by its q, k and v gradients, at the head layouts
+     of both one-chip cells.
 
 `--chips 4` runs only the trainer on a 2x2 data x model mesh and the same
 steps on a witness mesh in this process: one chip, or for a model one chip
@@ -40,6 +42,7 @@ import os
 import sys
 import time
 from contextlib import contextmanager
+from unittest import mock
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
@@ -75,6 +78,12 @@ SHARDED_STEP_RTOL = 2e-2        # later steps (3.0e-3, 3.7e-3)
 # away over the whole tree; a leaf moved 6.4x as far reads 5.4 (PERF.md).
 SHARDED_UPDATE_LEAF_RTOL = 0.35
 USEFUL_RATIO_RANGE = (0.5, 1.05)
+# Flash attention's q, k and v gradients from bf16 inputs against the f32
+# oracle's: max |g - g_ref| / max |g_ref|, per gradient (6.7e-3).
+FLASH_GRAD_RTOL = 3e-2
+# (batch, seq, q heads, kv heads, head_dim, window) of the one-chip cells.
+FLASH_LAYOUTS = {"qwen2-0.5b": (4, 1024, 14, 2, 64, None),
+                 "hymba-1.5b": (1, 4096, 25, 5, 64, 1024)}
 
 
 class SmokeFailure(RuntimeError):
@@ -143,8 +152,11 @@ def reference_phase() -> float:
     fwd = jax.jit(lambda p, b: loss_fn(p, cfg, b))
     chip_loss = float(fwd(params, batch))
     cpu = jax.devices("cpu")[0]
-    cpu_loss = float(fwd(jax.device_put(params, cpu),
-                         jax.device_put(batch, cpu)))
+    # The model picks its Pallas kernels by the process's default backend,
+    # the chip's here: trace the CPU program as a CPU process would.
+    with mock.patch.object(jax, "default_backend", lambda: "cpu"):
+        cpu_loss = float(jax.jit(lambda p, b: loss_fn(p, cfg, b))(
+            jax.device_put(params, cpu), jax.device_put(batch, cpu)))
     rel = _rel(chip_loss, cpu_loss)
     print(f"forward loss on {REF_TOKENS}: device {chip_loss:.6f} "
           f"cpu {cpu_loss:.6f} rel diff {rel:.3e} (bound {REF_LOSS_RTOL})")
@@ -195,6 +207,9 @@ def kernel_cases():
                 rand(1, (1, s, 2, 64), jnp.bfloat16),
                 rand(2, (1, s, 2, 64), jnp.bfloat16))
 
+    def flash_hymba():
+        return flash_inputs(*FLASH_LAYOUTS["hymba-1.5b"][:5])[:3]
+
     def rms():
         return rand(3, (s, 896), jnp.bfloat16), 1.0 + 0.1 * rand(4, (896,))
 
@@ -218,6 +233,10 @@ def kernel_cases():
     return [
         ("flash_attention", ops.flash_attention_op,
          ref.flash_attention_ref, flash, bf16_tol),
+        ("flash_attention_swa",
+         lambda *a, **k: ops.flash_attention_op(*a, window=1024, **k),
+         lambda *a: ref.flash_attention_ref(*a, window=1024), flash_hymba,
+         bf16_tol),
         ("rmsnorm_baseline", ops.rmsnorm_baseline_op, ref.rmsnorm_ref, rms,
          bf16_tol),
         ("rmsnorm_pipelined", ops.rmsnorm_op, ref.rmsnorm_ref, rms, bf16_tol),
@@ -255,6 +274,58 @@ def kernels_phase() -> dict:
               f"{used:.3e}")
         check(used <= 1.0, f"{name}: output outside the bound")
     return errors
+
+
+def flash_inputs(b, s, h, kv, hd):
+    """bf16 q, k, v and an f32 weight on the output, from fixed keys."""
+    import jax
+    import jax.numpy as jnp
+    ks = jax.random.split(jax.random.PRNGKey(20), 4)
+    return ((jax.random.normal(ks[0], (b, s, h, hd)) * 0.5).astype(
+        jnp.bfloat16),
+        (jax.random.normal(ks[1], (b, s, kv, hd)) * 0.5).astype(
+            jnp.bfloat16),
+        (jax.random.normal(ks[2], (b, s, kv, hd)) * 0.5).astype(
+            jnp.bfloat16),
+        jax.random.normal(ks[3], (b, s, h, hd)))
+
+
+def flash_grads_phase() -> dict:
+    """The flash kernel pair's q, k and v gradients of sum(out * w) against
+    the f32 oracle's at `highest` precision, at each one-chip cell's head
+    layout and rows: each gradient within FLASH_GRAD_RTOL of the oracle's
+    largest element."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.kernels import ops, ref
+
+    worst = {}
+    for cell, (b, s, h, kv, hd, window) in FLASH_LAYOUTS.items():
+        q, k, v, w = flash_inputs(b, s, h, kv, hd)
+
+        def grads(attend):
+            return jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+                attend(q, k, v).astype(jnp.float32) * w), (0, 1, 2)))
+        kernel = grads(lambda q, k, v: ops.flash_attention_op(
+            q, k, v, window=window, interpret=False)).lower(q, k, v).compile()
+        check("flash_attention_bwd" in kernel.as_text(),
+              f"{cell}: no flash backward kernel in the gradient")
+        got = kernel(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            want = grads(lambda q, k, v: ref.flash_attention_ref(
+                *(x.astype(jnp.float32) for x in (q, k, v)),
+                window=window))(q, k, v)
+        gaps = [float(np.max(np.abs(np.asarray(g, np.float32) - e))
+                      / np.max(np.abs(e)))
+                for g, e in zip(got, (np.asarray(x) for x in want))]
+        worst[cell] = max(gaps)
+        print(f"  flash gradients {cell} {tuple(q.shape)}: max |g - ref| / "
+              f"max |ref| dq {gaps[0]:.3e} dk {gaps[1]:.3e} dv {gaps[2]:.3e}"
+              f" (bound {FLASH_GRAD_RTOL})")
+        check(worst[cell] <= FLASH_GRAD_RTOL,
+              f"{cell}: flash gradients outside the bound")
+    return worst
 
 
 def _rel(a, b) -> float:
@@ -394,6 +465,8 @@ def main(argv=None) -> int:
             leo_phase(result, backend)
         with phase("Mosaic kernels vs oracles"):
             kernels_phase()
+        with phase("flash attention gradients vs oracle"):
+            flash_grads_phase()
 
     d = devices[0]
     print(json.dumps({"ok": True, "device": {

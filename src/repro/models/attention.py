@@ -1,11 +1,20 @@
 """Attention: GQA (full/causal), sliding-window, and MLA (DeepSeek-V2).
 
-Training/prefill attention uses a *chunked online-softmax* formulation (the
-pure-jnp flash-attention shape): Python-level query-chunk loop with a
-`lax.scan` over only the key chunks each query chunk can see, so causal and
-sliding-window masking skip work structurally instead of masking a full
-S x S score tensor.  This is both the XLA production path and the oracle the
-Pallas kernel in `repro.kernels.flash_attention` is validated against.
+Which softmax core a full-sequence GQA forward runs is chosen in
+`attn_forward`:
+
+- In a process whose default backend is a TPU, for a step on one device,
+  when `kernels/flash_attention.flash_attention_blocks` gives blocks for
+  the shapes: the Pallas flash kernel pair with its own backward.
+- Everywhere else: a *chunked online-softmax* in XLA ops (the pure-jnp
+  flash-attention shape, `chunked_attention`): a Python-level query-chunk
+  loop with a `lax.scan` over only the key chunks each query chunk can
+  see, so causal and sliding-window masking skip work structurally
+  instead of masking a full S x S score tensor.  That is every CPU
+  process (the tests, LEO's dry-run and hillclimb), every step sharded
+  over more than one device (XLA cannot partition a Mosaic kernel across
+  a mesh), and MLA.  It is also the oracle the kernel is validated
+  against.
 
 Decode uses a KV cache: full cache for "full" attention, a ring buffer of
 `window` entries for SWA, and the compressed (kv_lora + k_rope) cache with
@@ -21,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..kernels.flash_attention import flash_attention, flash_attention_blocks
 from .flags import FUSED_REGION_MARK, get_flags
 from .layers import apply_rope, dense_init, linear, rmsnorm, rope_cos_sin
 
@@ -148,6 +158,21 @@ def init_attn(key, cfg: ArchConfig, dtype) -> Params:
     return p
 
 
+def _attend_in_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                      cfg: ArchConfig) -> bool:
+    """Whether this forward attends in the Pallas kernel: on a TPU, for a
+    step on one device (by the mesh of `q`'s sharding and the mesh in
+    context), when the kernel takes the shapes."""
+    _, s, h, hd = q.shape
+    devices = max(jax.typeof(q).sharding.mesh.size,
+                  jax.sharding.get_abstract_mesh().size)
+    window = cfg.window if cfg.attention == "swa" else None
+    return (jax.default_backend() == "tpu" and devices <= 1
+            and v.shape[-1] == hd
+            and flash_attention_blocks(s, hd, h // k.shape[2], window)
+            is not None)
+
+
 def attn_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig,
                  positions: jnp.ndarray, chunk: int = 512) -> jnp.ndarray:
     """Full-sequence causal attention (training / prefill)."""
@@ -160,7 +185,10 @@ def attn_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig,
     q = apply_rope(q, cos, sin, cfg.rope_interleave)
     k = apply_rope(k, cos, sin, cfg.rope_interleave)
     window = cfg.window if cfg.attention == "swa" else None
-    out = chunked_attention(q, k, v, chunk=chunk, window=window)
+    if _attend_in_kernel(q, k, v, cfg):
+        out = flash_attention(q, k, v, window=window)
+    else:
+        out = chunked_attention(q, k, v, chunk=chunk, window=window)
     return linear(out.reshape(b, s, h * hd), p["wo"])
 
 

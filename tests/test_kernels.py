@@ -57,6 +57,130 @@ class TestFlashAttention:
                                    np.asarray(out_xla), atol=2e-5, rtol=2e-5)
 
 
+# (b, s, h, kv, hd, window, block_q, block_k): GQA groups 1, 5 and 7,
+# several query and key blocks in each, causal and windows of 70 and 100
+# (not multiples of the blocks), square and unequal blocks.
+FLASH_CASES = [(2, 128, 2, 2, 32, None, 32, 64),
+               (1, 192, 5, 1, 32, 70, 32, 32),
+               (2, 128, 7, 1, 32, None, 64, 32),
+               (1, 256, 7, 1, 32, 100, 64, 32)]
+
+
+def _flash_inputs(b, s, h, kv, hd, dtype=jnp.float32, seed=7):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (_rand(ks[0], (b, s, h, hd), dtype),
+            _rand(ks[1], (b, s, kv, hd), dtype),
+            _rand(ks[2], (b, s, kv, hd), dtype),
+            _rand(ks[3], (b, s, h, hd), jnp.float32))
+
+
+def _flash_grads(attn, q, k, v, w):
+    """d/d(q, k, v) of sum(attn(q, k, v) * w)."""
+    return jax.grad(lambda q, k, v: jnp.sum(
+        attn(q, k, v).astype(jnp.float32) * w), (0, 1, 2))(q, k, v)
+
+
+class TestFlashAttentionGrad:
+    """The kernel pair through its custom VJP against the naive oracle and
+    against the gradients of the model's chunked XLA path."""
+
+    @staticmethod
+    def _paths(window, bq, bk):
+        from repro.models.attention import chunked_attention
+        return {
+            "kernel": lambda q, k, v: ops.flash_attention_op(
+                q, k, v, window=window, block_q=bq, block_k=bk,
+                interpret=True),
+            "ref": lambda q, k, v: ref.flash_attention_ref(q, k, v,
+                                                           window=window),
+            "xla": lambda q, k, v: chunked_attention(q, k, v, chunk=32,
+                                                     window=window)}
+
+    @pytest.mark.parametrize("b,s,h,kv,hd,window,bq,bk", FLASH_CASES)
+    def test_forward_matches_ref_and_xla_path(self, b, s, h, kv, hd, window,
+                                              bq, bk):
+        q, k, v, _ = _flash_inputs(b, s, h, kv, hd)
+        paths = self._paths(window, bq, bk)
+        with jax.default_matmul_precision("highest"):
+            out = {name: np.asarray(f(q, k, v)) for name, f in paths.items()}
+        for name in ("ref", "xla"):
+            np.testing.assert_allclose(out["kernel"], out[name], atol=2e-5,
+                                       rtol=2e-5, err_msg=name)
+
+    @pytest.mark.parametrize("b,s,h,kv,hd,window,bq,bk", FLASH_CASES)
+    def test_grad_matches_ref_and_xla_path(self, b, s, h, kv, hd, window,
+                                           bq, bk):
+        q, k, v, w = _flash_inputs(b, s, h, kv, hd)
+        paths = self._paths(window, bq, bk)
+        with jax.default_matmul_precision("highest"):
+            grads = {name: _flash_grads(f, q, k, v, w)
+                     for name, f in paths.items()}
+        for name in ("ref", "xla"):
+            for arg, g, e in zip("qkv", grads["kernel"], grads[name]):
+                scale = float(jnp.max(jnp.abs(e)))
+                np.testing.assert_allclose(
+                    np.asarray(g), np.asarray(e), atol=1e-5 * scale,
+                    rtol=1e-4, err_msg=f"d{arg} against {name}")
+
+    def test_bf16_grad_matches_xla_path(self):
+        """In bf16, the MXU operands of both paths (q, k, v, P, dO, dS)
+        are bf16 and their sums f32: the gradients agree to a few bf16
+        roundings."""
+        q, k, v, w = _flash_inputs(1, 128, 4, 2, 32, jnp.bfloat16)
+        paths = self._paths(None, 32, 32)
+        grads = {name: _flash_grads(paths[name], q, k, v, w)
+                 for name in ("kernel", "xla")}
+        for arg, g, e in zip("qkv", grads["kernel"], grads["xla"]):
+            g, e = (np.asarray(x, np.float32) for x in (g, e))
+            np.testing.assert_allclose(g, e, atol=2e-2 * np.abs(e).max(),
+                                       err_msg=f"d{arg}")
+
+    @pytest.mark.parametrize("s,hd,groups,window,blocks", [
+        (1024, 64, 7, None, (512, 512)),    # qwen2-0.5b
+        (4096, 64, 5, 1024, (512, 512)),    # hymba-1.5b
+        (768, 64, 1, None, (256, 256)),     # 512 does not divide S
+        (4096, 64, 5, 512, (256, 256)),     # a window under two blocks
+        (1024, 128, 32, None, (128, 128)),  # VMEM bounds the block
+        (1000, 64, 1, None, None),          # no block divides S
+        (1024, 64, 1, 128, None),           # a window under two blocks
+        (1024, 512, 64, None, None),        # no block fits the VMEM
+    ])
+    def test_blocks_follow_shapes_window_and_vmem(self, s, hd, groups,
+                                                  window, blocks):
+        from repro.kernels.flash_attention import flash_attention_blocks
+        got = flash_attention_blocks(s, hd, groups, window)
+        assert got == blocks
+        if got:
+            assert all(s % blk == 0 for blk in got)
+
+
+class TestAttentionPathByPlatform:
+    @pytest.mark.parametrize("backend,devices,s,vd,kernel", [
+        ("tpu", 1, 1024, 64, True),
+        ("cpu", 1, 1024, 64, False),    # the CPU tests, dry-run, hillclimb
+        ("tpu", 4, 1024, 64, False),    # a sharded step: no partitioning
+        ("tpu", 1, 1000, 64, False),    # no kernel block divides S
+        ("tpu", 1, 1024, 32, False),    # a value width unlike the key's
+    ])
+    def test_attention_path_follows_backend_devices_and_shapes(
+            self, monkeypatch, backend, devices, s, vd, kernel):
+        from jax.sharding import AbstractMesh, AxisType
+        from repro.configs.lm_archs import QWEN2_0_5B
+        from repro.models import attention
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        mesh = AbstractMesh((devices, 1), ("data", "model"),
+                            axis_types=(AxisType.Auto,) * 2)
+        q = jax.ShapeDtypeStruct((1, s, 14, 64), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, s, 2, 64), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((1, s, 2, vd), jnp.bfloat16)
+        with jax.sharding.use_abstract_mesh(mesh):
+            chosen = []
+            jax.jit(lambda q, k, v: chosen.append(
+                attention._attend_in_kernel(q, k, v, QWEN2_0_5B))
+            ).trace(q, k, v)
+        assert chosen == [kernel]
+
+
 class TestRmsnorm:
     @pytest.mark.parametrize("r,d", [(32, 128), (64, 256), (8, 512)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

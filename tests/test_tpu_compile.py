@@ -64,10 +64,28 @@ def _compile(fn, shapes, sharding):
 BF16, F32 = jnp.bfloat16, jnp.float32
 
 # Each kernel at a real model width: flash at qwen2-0.5b's heads (14 q / 2
-# kv, head_dim 64), rmsnorm at its d_model, the selective scan at
+# kv, head_dim 64), and its forward and backward pair at the one-chip
+# cells' rows (qwen2-0.5b 4 x 1024, causal; hymba-1.5b 1 x 4096, 25 q / 5
+# kv, window 1024), rmsnorm at qwen2-0.5b's d_model, the selective scan at
 # hymba-1.5b's d_inner 3200 and state 16 over its 4096-token rows (the
 # backward with the forward it differentiates), mLSTM (4 heads of 192) and
 # sLSTM (d 768) at xlstm-125m's.
+FLASH_ARGS = {"qwen": ((4, 1024, 14, 2, 64), None),
+              "hymba": ((1, 4096, 25, 5, 64), 1024)}
+
+
+def _flash_case(cell, grad):
+    (b, s, h, kv, hd), window = FLASH_ARGS[cell]
+
+    def attend(q, k, v):
+        return ops.flash_attention_op(q, k, v, window=window,
+                                      interpret=False)
+    fn = attend if not grad else jax.grad(
+        lambda *a: attend(*a).astype(F32).sum(), argnums=(0, 1, 2))
+    return fn, [((b, s, h, hd), BF16), ((b, s, kv, hd), BF16),
+                ((b, s, kv, hd), BF16)]
+
+
 SELECTIVE_SCAN_ARGS = [((1, 4096, 3200), F32), ((1, 4096, 3200), BF16),
                        ((1, 4096, 16), F32), ((1, 4096, 16), F32),
                        ((3200, 16), F32)]
@@ -93,6 +111,10 @@ KERNELS = {
         lambda *a: ops.selective_scan_op(*a, interpret=False),
         SELECTIVE_SCAN_ARGS),
     "selective_scan_bwd": (_selective_scan_grad, SELECTIVE_SCAN_ARGS),
+    "flash_attention_fwd_qwen": _flash_case("qwen", grad=False),
+    "flash_attention_bwd_qwen": _flash_case("qwen", grad=True),
+    "flash_attention_fwd_hymba": _flash_case("hymba", grad=False),
+    "flash_attention_bwd_hymba": _flash_case("hymba", grad=True),
     "mlstm_chunkwise": (
         lambda q, k, v, i, f: ops.mlstm_chunkwise_op(q, k, v, i, f, chunk=64,
                                                      interpret=False),
@@ -110,13 +132,14 @@ def test_kernel_compiles_with_mosaic(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _hymba_step(layers: int, batch: int, seq_len: int, shardings):
-    """hymba-1.5b's train step at its widths, cut to `layers` layers, and
-    its arguments' shapes, placed by `shardings(cfg, state)`, which gives
-    the state's and the batch's shardings."""
-    from repro.configs.lm_archs import HYMBA_1_5B
+def _train_step(arch: str, layers: int, batch: int, seq_len: int,
+                shardings):
+    """An architecture's train step at its widths, cut to `layers` layers,
+    and its arguments' shapes, placed by `shardings(cfg, state)`, which
+    gives the state's and the batch's shardings."""
+    from repro.configs import get_config
     from repro.runtime.steps import init_train_state, make_train_step
-    cfg = dataclasses.replace(HYMBA_1_5B, n_layers=layers)
+    cfg = dataclasses.replace(get_config(arch), n_layers=layers)
     state = jax.eval_shape(lambda: init_train_state(jax.random.PRNGKey(0),
                                                     cfg))
     batch = {k: jax.ShapeDtypeStruct((batch, seq_len), jnp.int32)
@@ -126,6 +149,10 @@ def _hymba_step(layers: int, batch: int, seq_len: int, shardings):
     args = (jax.tree.map(place, state, state_sh),
             {k: place(v, batch_sh[k]) for k, v in batch.items()})
     return jax.jit(make_train_step(cfg), donate_argnums=(0,)), args
+
+
+def _hymba_step(layers: int, batch: int, seq_len: int, shardings):
+    return _train_step("hymba-1.5b", layers, batch, seq_len, shardings)
 
 
 def _on(sharding):
@@ -146,23 +173,76 @@ def _ssm_carries(text, d_inner=3200):
             if " while(" in line and f",{d_inner},16]" in line]
 
 
+def _kernel_scopes(module, prefix):
+    """{kernel name: the model scopes of its calls} for the Mosaic kernels
+    whose name starts with `prefix`."""
+    from repro.core.cct import InstructionScopes
+    from repro.models.scopes import MODEL_SCOPES
+    scope = InstructionScopes(module, MODEL_SCOPES)
+    found = {}
+    for i in _kernels(module):
+        name = i.name.rsplit(".", 1)[0]
+        if name.startswith(prefix):
+            found.setdefault(name, set()).add(scope(i))
+    return found
+
+
 def test_hymba_step_scans_ssm_in_the_kernel(one_chip, monkeypatch):
     """On one chip, in a process whose default backend is the TPU, each
     layer's SSM scan is the selective-scan kernel pair, inside the model's
     `ssm` scope, and no chunk scan over an f32[batch, d_inner, state]
     carry is left."""
-    from repro.core.cct import InstructionScopes
-    from repro.models.scopes import MODEL_SCOPES
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     step, args = _hymba_step(1, 1, 256, _on(one_chip))
     text = step.lower(*args).compile().as_text()
-    module = parse_hlo(text)
-    kernels = _kernels(module)
-    names = sorted({i.name.rsplit(".", 1)[0] for i in kernels})
-    assert names == ["selective_scan_bwd", "selective_scan_fwd"], names
-    scope = InstructionScopes(module, MODEL_SCOPES)
-    assert {scope(i) for i in kernels} == {"ssm"}
+    scopes = _kernel_scopes(parse_hlo(text), "selective_scan")
+    assert scopes == {"selective_scan_bwd": {"ssm"},
+                      "selective_scan_fwd": {"ssm"}}, scopes
     assert not _ssm_carries(text)
+
+
+def _attention_carries(text):
+    """`chunked_attention`'s key-chunk `while` loops: their carry holds
+    the f32 accumulator [batch, heads, 512, 64]."""
+    return [line for line in text.splitlines()
+            if " while(" in line and ",512,64]" in line and "f32[" in line]
+
+
+FLASH_KERNELS = {"flash_attention_bwd_dkv": {"attn"},
+                 "flash_attention_bwd_dq": {"attn"},
+                 "flash_attention_fwd": {"attn"}}
+
+
+@pytest.mark.parametrize("arch,batch,seq_len", [("qwen2-0.5b", 4, 1024),
+                                                ("hymba-1.5b", 1, 4096)])
+def test_one_chip_step_attends_in_the_flash_kernels(arch, batch, seq_len,
+                                                    one_chip, monkeypatch):
+    """On one chip, in a process whose default backend is the TPU, a layer
+    of each one-chip cell's width at the cell's rows attends in the flash
+    kernel pair, inside the model's `attn` scope, and no key-chunk `while`
+    of `chunked_attention` is left."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args = _train_step(arch, 1, batch, seq_len, _on(one_chip))
+    text = step.lower(*args).compile().as_text()
+    scopes = _kernel_scopes(parse_hlo(text), "flash_attention")
+    assert scopes == FLASH_KERNELS, scopes
+    assert not _attention_carries(text)
+
+
+def test_sharded_step_keeps_chunked_attention(v5e_2x2, monkeypatch):
+    """qwen2-0.5b's step with `launch/train.py`'s shardings over the whole
+    v5e:2x2 attends in `chunked_attention`'s key-chunk loops and holds no
+    flash kernel: a Mosaic kernel cannot be partitioned across devices."""
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import train_shardings
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_host_mesh(2, devices=v5e_2x2.devices)
+    step, args = _train_step(
+        "qwen2-0.5b", 1, 4, 1024,
+        lambda cfg, state: train_shardings(mesh, cfg, state))
+    text = step.lower(*args).compile().as_text()
+    assert not _kernel_scopes(parse_hlo(text), "flash_attention")
+    assert _attention_carries(text)
 
 
 def test_kernel_scan_cuts_hymba_step_temporaries(one_chip, monkeypatch):
