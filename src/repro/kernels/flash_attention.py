@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .placement import on_tpu
+
 _NEG_INF = -1e30
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))     # a @ b.T
@@ -415,7 +417,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     block_q, block_k = min(block_q, s), min(block_k, s)
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     spec = _Spec(causal, window, block_q, block_k, interpret)
     qh = (q * (1.0 / math.sqrt(hd))).reshape(b, s, kv, groups, hd)
     qh = qh.transpose(0, 2, 3, 1, 4)

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .placement import on_tpu
+
 # Mosaic contracts f32 operands in one bf16 pass unless asked for full f32.
 _F32 = jax.lax.Precision.HIGHEST
 
@@ -99,7 +101,7 @@ def mlstm_chunkwise(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     assert s % chunk == 0
     nc = s // chunk
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
 
     kernel = functools.partial(_mlstm_kernel, chunk=chunk)
     qkv_spec = pl.BlockSpec((1, 1, chunk, hd),

@@ -1,8 +1,10 @@
-"""Jitted public wrappers for the Pallas kernels.
+"""Jitted forms of the Pallas kernels, for the kernel tests and
+`chip_smoke.py`.
 
-`interpret=None` auto-selects: compiled Mosaic on TPU, interpret mode on CPU
-(the validation path this container uses).  These are the entry points model
-code calls when `attention_impl="pallas"` etc.
+`interpret=None` auto-selects: compiled Mosaic in a TPU process, interpret
+mode elsewhere (`placement.on_tpu`).  The model calls the kernels
+themselves (`flash_attention.flash_attention`, `ssm_scan.selective_scan`)
+inside its own jitted step, where `placement.on_one_tpu` allows.
 """
 from __future__ import annotations
 

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .placement import on_tpu
+
 
 # -- baseline: implicit blockspec pipeline ------------------------------------
 
@@ -46,7 +48,7 @@ def rmsnorm_baseline(x: jnp.ndarray, scale: jnp.ndarray, *,
     block_rows = min(block_rows, r)
     assert r % block_rows == 0
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     return pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=(r // block_rows,),
@@ -96,7 +98,7 @@ def rmsnorm_pipelined(x: jnp.ndarray, scale: jnp.ndarray, *,
     assert r % block_rows == 0
     n_blocks = r // block_rows
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     return pl.pallas_call(
         functools.partial(_rmsnorm_pipelined_kernel, eps=eps,
                           block_rows=block_rows, n_blocks=n_blocks),

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .placement import on_tpu
+
 
 def _slstm_kernel(xg_ref, r_ref, o_ref, c_ref, n_ref, h_ref, m_ref, *,
                   chunk: int, d: int):
@@ -71,7 +73,7 @@ def slstm_scan(xg: jnp.ndarray, r: jnp.ndarray, *, chunk: int = 64,
     assert s % chunk == 0
     nc = s // chunk
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
 
     return pl.pallas_call(
         functools.partial(_slstm_kernel, chunk=chunk, d=d),

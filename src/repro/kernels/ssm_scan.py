@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .placement import on_tpu
+
 _LANES = 128
 _MAX_TILE_LANES = 1024
 
@@ -273,5 +275,5 @@ def selective_scan(dt: jnp.ndarray, x: jnp.ndarray, b_sel: jnp.ndarray,
     assert chunk and s % chunk == 0 and chunk % _UNROLL == 0 and \
         din % _LANES == 0, (s, din, chunk)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     return _selective_scan(chunk, interpret, dt, x, b_sel, c_sel, a_rate)

@@ -44,6 +44,14 @@ def _sharding_tree(mesh, spec_tree):
                         is_leaf=lambda x: isinstance(x, P))
 
 
+def _placed(tree, shardings):
+    """Abstract operands that carry their shardings in their type: the step
+    reads the mesh it runs over from its operands
+    (`kernels.placement.step_mesh`)."""
+    return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=s), tree, shardings)
+
+
 def lower_cell(cfg, shape, mesh, opts=None):
     """Lower + compile one (arch, shape, mesh) cell. Returns (lowered,
     compiled, seconds)."""
@@ -57,8 +65,6 @@ def lower_cell(cfg, shape, mesh, opts=None):
     )
     from . import specs as S
 
-    from ..parallel.context import set_current_mesh
-    set_current_mesh(mesh)
     rules = ShardingRules(mesh, cfg)
     if opts is None:
         import numpy as np
@@ -72,66 +78,56 @@ def lower_cell(cfg, shape, mesh, opts=None):
             batch = S.batch_specs(cfg, shape)
             pspecs = rules.param_specs(state["params"])
             ospecs = rules.opt_specs(state["opt"], state["params"])
-            state_specs = {"params": pspecs, "opt": ospecs, "step": P()}
-            bspecs = rules.batch_specs(cfg, shape)
+            state_sh = _sharding_tree(
+                mesh, {"params": pspecs, "opt": ospecs, "step": P()})
+            batch_sh = _sharding_tree(mesh, rules.batch_specs(cfg, shape))
             step = make_train_step(cfg, options=opts)
             lowered = jax.jit(
                 step,
                 donate_argnums=(0,),  # train state updates in place
-                in_shardings=(_sharding_tree(mesh, state_specs),
-                              _sharding_tree(mesh, bspecs)),
-                out_shardings=(_sharding_tree(mesh, state_specs),
-                               None),
-            ).lower(state, batch)
+                in_shardings=(state_sh, batch_sh),
+                out_shardings=(state_sh, None),
+            ).lower(_placed(state, state_sh), _placed(batch, batch_sh))
         elif shape.kind == "prefill":
             params = S.abstract_params(cfg)
             batch = S.batch_specs(cfg, shape)
-            pspecs = rules.param_specs(params)
-            bspecs = rules.batch_specs(cfg, shape)
+            params_sh = _sharding_tree(mesh, rules.param_specs(params))
+            batch_sh = _sharding_tree(mesh, rules.batch_specs(cfg, shape))
             step = make_prefill_step(cfg, chunk=min(512, shape.seq_len))
             lowered = jax.jit(
                 step,
-                in_shardings=(_sharding_tree(mesh, pspecs),
-                              _sharding_tree(mesh, bspecs)),
+                in_shardings=(params_sh, batch_sh),
                 out_shardings=NamedSharding(mesh, rules.logits_spec(shape)),
-            ).lower(params, batch)
+            ).lower(_placed(params, params_sh), _placed(batch, batch_sh))
         else:  # decode
             params = S.abstract_params(cfg)
             dstate = S.abstract_decode_state(cfg, shape)
-            pspecs = rules.param_specs(params)
-            sspecs = rules.decode_state_specs(dstate, shape)
-            bspecs = rules.batch_specs(cfg, shape)
+            batch = S.batch_specs(cfg, shape)
+            params_sh = _sharding_tree(mesh, rules.param_specs(params))
+            state_sh = _sharding_tree(mesh,
+                                      rules.decode_state_specs(dstate, shape))
+            tok_sh = NamedSharding(mesh, rules.batch_specs(cfg, shape)["token"])
+            pos_sh = NamedSharding(mesh, P())
             step = make_serve_step(cfg)
-            tok_sharding = NamedSharding(mesh, bspecs["token"])
             lowered = jax.jit(
                 step,
                 donate_argnums=(1,),  # KV cache / state updates in place
-                in_shardings=(_sharding_tree(mesh, pspecs),
-                              _sharding_tree(mesh, sspecs),
-                              tok_sharding, NamedSharding(mesh, P())),
-                out_shardings=(tok_sharding, None,
-                               _sharding_tree(mesh, sspecs)),
-            ).lower(params, dstate, S.batch_specs(cfg, shape)["token"],
-                    S.batch_specs(cfg, shape)["pos"])
+                in_shardings=(params_sh, state_sh, tok_sh, pos_sh),
+                out_shardings=(tok_sh, None, state_sh),
+            ).lower(_placed(params, params_sh), _placed(dstate, state_sh),
+                    _placed(batch["token"], tok_sh),
+                    _placed(batch["pos"], pos_sh))
         compiled = lowered.compile()
     return lowered, compiled, time.time() - t0
 
 
 def _parse_flags(spec: str) -> dict:
+    """`name=value,...` -> {name: value}, for `models.flags.flags`."""
     out = {}
     for part in (spec or "").split(","):
-        if not part.strip():
-            continue
-        k, _, v = part.partition("=")
-        v = v.strip()
-        if v.lower() in ("true", "false"):
-            val = v.lower() == "true"
-        else:
-            try:
-                val = int(v)
-            except ValueError:
-                val = v
-        out[k.strip()] = val
+        if part.strip():
+            k, _, v = part.partition("=")
+            out[k.strip()] = v.strip()
     return out
 
 
@@ -248,9 +244,9 @@ def main() -> None:
     ap.add_argument("--hw", default="tpu_v5e")
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--flags", default="",
-                    help="model flags, e.g. attention_impl=pallas_fused,"
-                         "ssm_fused=true,ssm_pallas=true,"
-                         "moe_impl=ep_shardmap")
+                    help="model flags (models/flags.py), e.g. "
+                         "attention_impl=pallas_fused,"
+                         "ssm_impl=pallas_fused,moe_impl=ep_shardmap")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="dump the analysis-cache/latency metrics "
                          "(Prometheus text format) to PATH after the sweep")

@@ -38,15 +38,16 @@ CELLS = {
         "arch": "hymba-1.5b", "shape": "train_4k",
         "variants": [
             ("baseline", {}, {}),
-            ("ssm_fused", {"ssm_fused": True}, {}),
+            ("ssm_fused", {"ssm_impl": "chunk_fused"}, {}),
             ("ssm_fused+flash",
-             {"ssm_fused": True, "attention_impl": "pallas_fused"}, {}),
+             {"ssm_impl": "chunk_fused", "attention_impl": "pallas_fused"},
+             {}),
             ("ssm+flash+mb2",
-             {"ssm_fused": True, "attention_impl": "pallas_fused"},
+             {"ssm_impl": "chunk_fused", "attention_impl": "pallas_fused"},
              {"microbatch": 2}),
             ("ssm_pallas+flash",
-             {"ssm_fused": True, "ssm_pallas": True,
-              "attention_impl": "pallas_fused"}, {}),
+             {"ssm_impl": "pallas_fused", "attention_impl": "pallas_fused"},
+             {}),
         ],
     },
     "dsv2": {

@@ -3,9 +3,10 @@
 Which softmax core a full-sequence GQA forward runs is chosen in
 `attn_forward`:
 
-- In a process whose default backend is a TPU, for a step on one device,
-  when `kernels/flash_attention.flash_attention_blocks` gives blocks for
-  the shapes: the Pallas flash kernel pair with its own backward.
+- Where the one placement rule of the Pallas kernels holds
+  (`kernels/placement.on_one_tpu`: a TPU process, a step on one device)
+  and `kernels/flash_attention.flash_attention_blocks` gives blocks for the
+  shapes: the Pallas flash kernel pair with its own backward.
 - Everywhere else: a *chunked online-softmax* in XLA ops (the pure-jnp
   flash-attention shape, `chunked_attention`): a Python-level query-chunk
   loop with a `lax.scan` over only the key chunks each query chunk can
@@ -23,6 +24,7 @@ DeepSeek-V2 paper rather than naive per-step decompression.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Dict, Optional, Tuple
 
@@ -31,6 +33,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from ..kernels.flash_attention import flash_attention, flash_attention_blocks
+from ..kernels.placement import on_one_tpu
 from .flags import FUSED_REGION_MARK, get_flags
 from .layers import apply_rope, dense_init, linear, rmsnorm, rope_cos_sin
 
@@ -72,6 +75,10 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         win_chunks = max(1, -(-window // chunk))  # ceil
 
     row_ids = jnp.arange(chunk)
+    # "pallas_fused" cost-models the validated Pallas flash kernel
+    # (kernels/flash_attention.py): the whole key sweep runs as one kernel
+    # with (m, l, acc) resident in VMEM scratch.
+    fused = get_flags().attention_impl == "pallas_fused"
 
     outputs = []
     for i in range(n_chunks):
@@ -105,15 +112,8 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         l0 = jnp.zeros((b, h, chunk), jnp.float32)
         a0 = jnp.zeros((b, h, chunk, vd), jnp.float32)
         js = jnp.arange(lo, i + 1)
-        if get_flags().attention_impl == "pallas_fused":
-            # Cost-model the validated Pallas flash kernel (see
-            # repro/kernels/flash_attention.py): the whole key sweep runs
-            # as one kernel with (m, l, acc) resident in VMEM scratch.
-            with jax.named_scope(FUSED_REGION_MARK):
-                (m, l, acc), _ = jax.lax.scan(
-                    kv_step, (m0, l0, a0), (kc[lo:i + 1], vc[lo:i + 1], js))
-                out = acc / jnp.maximum(l, 1e-30)[..., None]
-        else:
+        with (jax.named_scope(FUSED_REGION_MARK) if fused
+              else contextlib.nullcontext()):
             (m, l, acc), _ = jax.lax.scan(
                 kv_step, (m0, l0, a0), (kc[lo:i + 1], vc[lo:i + 1], js))
             out = acc / jnp.maximum(l, 1e-30)[..., None]
@@ -160,15 +160,11 @@ def init_attn(key, cfg: ArchConfig, dtype) -> Params:
 
 def _attend_in_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       cfg: ArchConfig) -> bool:
-    """Whether this forward attends in the Pallas kernel: on a TPU, for a
-    step on one device (by the mesh of `q`'s sharding and the mesh in
-    context), when the kernel takes the shapes."""
+    """Whether this forward attends in the Pallas kernel: where
+    `on_one_tpu(q)` holds and the kernel takes the shapes."""
     _, s, h, hd = q.shape
-    devices = max(jax.typeof(q).sharding.mesh.size,
-                  jax.sharding.get_abstract_mesh().size)
     window = cfg.window if cfg.attention == "swa" else None
-    return (jax.default_backend() == "tpu" and devices <= 1
-            and v.shape[-1] == hd
+    return (on_one_tpu(q) and v.shape[-1] == hd
             and flash_attention_blocks(s, hd, h // k.shape[2], window)
             is not None)
 

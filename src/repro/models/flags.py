@@ -1,8 +1,9 @@
 """Model-level optimization flags (the §Perf hillclimb levers).
 
-Explicit global switches so the dry-run can lower baseline and optimized
-variants of the same architecture without threading options through every
-layer:
+Three process-wide switches, so that LEO's dry-run can lower baseline and
+optimized variants of one architecture without threading options through
+every layer.  The hillclimb's variants (`launch/hillclimb.CELLS`) and the
+dry-run's `--flags` set them, inside `flags(...)`; nothing else does.
 
   attention_impl : "xla"          — chunked online-softmax in pure XLA ops
                                     (paper-faithful baseline; the online-
@@ -13,45 +14,48 @@ layer:
                                     tagged with a fused-region scope and
                                     LEO's parser prices it as VMEM-resident
                                     (inputs/outputs only), FLOPs unchanged.
-  ssm_fused      : False          — discretize (a, bx) for the whole
+  ssm_impl       : "xla"          — discretize (a, bx) for the whole
                                     sequence up front (materializes
                                     B x S x d_inner x N in HBM);
-                   True           — discretize per chunk inside the scan
-                                    (transient, fuses into the chunk body).
-                   Both shape the XLA chunk scan: every CPU process (the
-                   tests, the dry-run, the hillclimb) and every step
-                   sharded over several devices runs it.  A one-device
-                   step in a TPU process scans in the Pallas selective-scan
-                   kernel wherever it applies, and these two flags then
-                   change nothing (`models/ssm.py`).
+                   "chunk_fused"  — discretize per chunk inside the scan
+                                    (transient, fuses into the chunk body);
+                   "pallas_fused" — "chunk_fused", with the chunk scan
+                                    tagged as the Pallas selective-scan
+                                    kernel, priced as attention's is.
+                   All three shape the XLA chunk scan: every CPU process
+                   (the tests, the dry-run, the hillclimb) and every step
+                   sharded over several devices runs it.  Where the Pallas
+                   kernels' placement rule holds (`kernels/placement.py`),
+                   a step scans in the selective-scan kernel wherever it
+                   applies, and this flag changes nothing (`models/ssm.py`).
   moe_impl       : "global"       — routing over the global token axis
                                     (XLA inserts distributed sort/gather
                                     collectives);
                    "ep_shardmap"  — shard_map local routing + all-to-all
                                     expert parallelism over the "model"
-                                    axis.
+                                    axis of the mesh the step runs over.
 """
 from __future__ import annotations
 
 from contextlib import contextmanager
 from dataclasses import dataclass, replace
 
+_VALUES = {"attention_impl": ("xla", "pallas_fused"),
+           "ssm_impl": ("xla", "chunk_fused", "pallas_fused"),
+           "moe_impl": ("global", "ep_shardmap")}
+
 
 @dataclass(frozen=True)
 class ModelFlags:
     attention_impl: str = "xla"
-    ssm_fused: bool = False
-    ssm_pallas: bool = False      # cost-model the Pallas selective-scan
-                                  # kernel in the XLA chunk scan
-    mlstm_pallas: bool = False    # cost-model the Pallas mlstm_chunkwise kernel
-    sequence_parallel: bool = False  # shard residual-stream activations over
-                                     # "model" between blocks: XLA turns the
-                                     # Megatron activation all-reduces into
-                                     # reduce-scatter + all-gather pairs
+    ssm_impl: str = "xla"
     moe_impl: str = "global"
-    fsdp_threshold_mb: int = 128  # per-shard size above which weights are
-                                  # dp-sharded; raise when bf16 params fit
-                                  # per chip (FSDP re-gathers per microstep)
+
+    def __post_init__(self):
+        for name, allowed in _VALUES.items():
+            if getattr(self, name) not in allowed:
+                raise ValueError(f"{name}={getattr(self, name)!r}: "
+                                 f"expected one of {allowed}")
 
 
 _FLAGS = ModelFlags()
@@ -62,12 +66,6 @@ FUSED_REGION_MARK = "pallas_fused_region"
 
 
 def get_flags() -> ModelFlags:
-    return _FLAGS
-
-
-def set_flags(**kwargs) -> ModelFlags:
-    global _FLAGS
-    _FLAGS = replace(_FLAGS, **kwargs)
     return _FLAGS
 
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..kernels.placement import step_mesh
 from .layers import dense_init, init_mlp, linear, mlp
 
 Params = Dict[str, jnp.ndarray]
@@ -176,14 +177,14 @@ def moe_forward_ep(p: Params, x: jnp.ndarray, cfg: ArchConfig
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """shard_map expert-parallel MoE: routing stays shard-local, expert
     weights stay stationary, the only collectives are two all-to-alls along
-    the "model" axis.  Falls back to the global path off-mesh."""
+    the "model" axis of the mesh `x`'s step runs over.  Falls back to the
+    global path off a mesh, without a "model" axis, or where that axis does
+    not divide the experts."""
     from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.context import get_current_mesh
-
-    mesh = get_current_mesh()
-    if mesh is None or "model" not in mesh.axis_names or \
+    mesh = step_mesh(x)
+    if "model" not in mesh.axis_names or \
             cfg.n_experts % mesh.shape["model"] != 0:
         return moe_forward(p, x, cfg)
     dp_axes = tuple(a for a in mesh.axis_names if a != "model")

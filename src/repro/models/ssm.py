@@ -6,30 +6,33 @@ Recurrence per channel c with state size N:
 
 Which scan a full-sequence forward runs is chosen in `ssm_forward`:
 
-- In a process whose default backend is a TPU, for a step on one device,
+- Where the one placement rule of the Pallas kernels holds
+  (`kernels/placement.on_one_tpu`: a TPU process, a step on one device),
   with d_inner a multiple of 128 and a kernel chunk that divides S: one
   Pallas kernel with its own backward (`kernels/ssm_scan.selective_scan`),
   fed the per-token terms dt, x, B, C; the B x S x d_inner x N terms exist
   only in VMEM.
 - Everywhere else: a `lax.scan` over sequence *chunks* with an associative
-  scan inside each chunk (`_xla_scan`, shaped by the `ssm_fused` /
-  `ssm_pallas` flags).  That is every CPU process (the tests, LEO's dry-run
-  and hillclimb), and every step sharded over more than one device: XLA
-  cannot partition a Mosaic kernel across a mesh.
+  scan inside each chunk (`_xla_scan`, shaped by the `ssm_impl` flag).
+  That is every CPU process (the tests, LEO's dry-run and hillclimb), and
+  every step sharded over more than one device: XLA cannot partition a
+  Mosaic kernel across a mesh.
 
 Decode carries `h` as O(1) state, which is what makes `long_500k` feasible
 for SSM archs.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..kernels.placement import on_one_tpu
 from ..kernels.ssm_scan import selective_scan, selective_scan_chunk
-from .flags import get_flags
+from .flags import FUSED_REGION_MARK, get_flags
 from .layers import dense_init, linear
 
 Params = Dict[str, jnp.ndarray]
@@ -76,7 +79,8 @@ def _xla_scan(p: Params, xin: jnp.ndarray, cfg: ArchConfig,
         a2, b2 = p2
         return a1 * a2, b1 * a2 + b2
 
-    if get_flags().ssm_fused:
+    model_flags = get_flags()
+    if model_flags.ssm_impl != "xla":
         # Discretize per chunk inside the scan: (a, bx) exist only as
         # (B, chunk, din, N) transients fused into the chunk body — the
         # B x S x din x N materialization LEO flags in the baseline is gone.
@@ -91,15 +95,13 @@ def _xla_scan(p: Params, xin: jnp.ndarray, cfg: ArchConfig,
             return h[:, -1], y
 
         h0 = jnp.zeros((b, din, cfg.ssm_state), jnp.float32)
-        if get_flags().ssm_pallas:
-            # Cost-model the validated Pallas selective-scan kernel
-            # (repro/kernels/ssm_scan.py): discretized terms and the
-            # associative-scan stages live in VMEM; HBM traffic is the
-            # xin chunks in and y chunks out.
-            from .flags import FUSED_REGION_MARK
-            with jax.named_scope(FUSED_REGION_MARK):
-                _, ys = jax.lax.scan(chunk_step, h0, xin_c)
-        else:
+        # "pallas_fused" cost-models the validated Pallas selective-scan
+        # kernel (kernels/ssm_scan.py): discretized terms and the
+        # associative-scan stages live in VMEM; HBM traffic is the xin
+        # chunks in and y chunks out.
+        with (jax.named_scope(FUSED_REGION_MARK)
+              if model_flags.ssm_impl == "pallas_fused"
+              else contextlib.nullcontext()):
             _, ys = jax.lax.scan(chunk_step, h0, xin_c)
     else:
         a, bx, csel = _discretize(p, xin)
@@ -135,13 +137,10 @@ def _kernel_scan(p: Params, xin: jnp.ndarray,
 
 
 def _scan_in_kernel(xin: jnp.ndarray, cfg: ArchConfig) -> bool:
-    """Whether this forward scans in the Pallas kernel: on a TPU, for a
-    step on one device (by the mesh of `xin`'s sharding and the mesh in
-    context), when the kernel takes the shapes."""
+    """Whether this forward scans in the Pallas kernel: where
+    `on_one_tpu(xin)` holds and the kernel takes the shapes."""
     _, s, din = xin.shape
-    devices = max(jax.typeof(xin).sharding.mesh.size,
-                  jax.sharding.get_abstract_mesh().size)
-    return (jax.default_backend() == "tpu" and devices <= 1
+    return (on_one_tpu(xin)
             and selective_scan_chunk(s, din, cfg.ssm_state) is not None)
 
 

@@ -125,34 +125,12 @@ def init_params(rng, cfg: ArchConfig) -> Params:
 
 # -- block forward -----------------------------------------------------------------
 
-def _sp_constraint(x: jnp.ndarray) -> jnp.ndarray:
-    """Megatron-style sequence parallelism: between blocks the residual
-    stream lives sequence-sharded over "model", so the row-parallel
-    projections' all-reduces decompose into reduce-scatter (+ all-gather at
-    the next consumer) — half the wire bytes, and norms compute on 1/tp of
-    the tokens."""
-    from .flags import get_flags
-    if not get_flags().sequence_parallel:
-        return x
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.context import get_current_mesh
-    mesh = get_current_mesh()
-    if mesh is None or "model" not in mesh.axis_names or \
-            x.ndim != 3 or x.shape[1] % mesh.shape["model"] != 0:
-        return x
-    dp = tuple(a for a in mesh.axis_names if a != "model")
-    dp = dp if len(dp) > 1 else dp[0]
-    return jax.lax.with_sharding_constraint(x, P(dp, "model", None))
-
-
 def _block_forward(p: Params, x: jnp.ndarray, desc: Tuple[str, str],
                    cfg: ArchConfig, positions: jnp.ndarray,
                    chunk: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full-sequence block. Returns (x, aux_loss)."""
     mixer, ffn = desc
     aux = jnp.zeros((), jnp.float32)
-    x = _sp_constraint(x)
     with jax.named_scope(scopes.NORM):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if mixer == "hybrid":

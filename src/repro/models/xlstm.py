@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from .flags import FUSED_REGION_MARK, get_flags
 from .layers import dense_init, linear, rmsnorm
 
 Params = Dict[str, jnp.ndarray]
@@ -112,14 +111,7 @@ def mlstm_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig,
     xs = (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(kc, 1, 0),
           jnp.moveaxis(vc, 1, 0), jnp.moveaxis(lic, 1, 0),
           jnp.moveaxis(lfc, 1, 0))
-    if get_flags().mlstm_pallas:
-        # Cost-model the validated Pallas chunkwise kernel
-        # (repro/kernels/mlstm_scan.py): the (hd x hd) matrix state and all
-        # intra-chunk gate/score intermediates live in VMEM scratch.
-        with jax.named_scope(FUSED_REGION_MARK):
-            (_, _, _), ys = jax.lax.scan(chunk_step, (c0, n0, m0), xs)
-    else:
-        (_, _, _), ys = jax.lax.scan(chunk_step, (c0, n0, m0), xs)
+    (_, _, _), ys = jax.lax.scan(chunk_step, (c0, n0, m0), xs)
     y = jnp.moveaxis(ys, 0, 1).reshape(b, s, din).astype(x.dtype)
     y = rmsnorm(y, p["norm"], cfg.norm_eps)
     y = y * jax.nn.silu(zgate)
@@ -205,15 +197,7 @@ def slstm_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
     init = (jnp.zeros((b, d), jnp.float32), jnp.zeros((b, d), jnp.float32),
             jnp.zeros((b, d), jnp.float32), jnp.full((b, d), -1e30,
                                                      jnp.float32))
-    if get_flags().mlstm_pallas:
-        # Cost-model the validated Pallas sLSTM kernel
-        # (repro/kernels/slstm_scan.py): states + recurrent weights live in
-        # VMEM across the whole sequence; the unfused backward otherwise
-        # accumulates full-sequence gradient stacks every timestep.
-        with jax.named_scope(FUSED_REGION_MARK):
-            _, hs = jax.lax.scan(step, init, jnp.moveaxis(xg, 1, 0))
-    else:
-        _, hs = jax.lax.scan(step, init, jnp.moveaxis(xg, 1, 0))
+    _, hs = jax.lax.scan(step, init, jnp.moveaxis(xg, 1, 0))
     y = jnp.moveaxis(hs, 0, 1).astype(x.dtype)
     # gated ffn (4/3)
     f = jax.nn.silu(linear(y, p["ffn_gate"])) * linear(y, p["ffn_up"])

@@ -215,9 +215,8 @@ class ShardingRules:
             if is_moe and get_flags().moe_impl == "ep_shardmap":
                 return spec  # stationary expert weights: no FSDP
             if self.fsdp:
-                threshold = get_flags().fsdp_threshold_mb * 2**20
                 spec = _auto_shard_dp(spec, leaf_sds.shape, self.mesh,
-                                      self.dp_axes, threshold)
+                                      self.dp_axes, _FSDP_THRESHOLD_BYTES)
             return spec
         return jax.tree_util.tree_map_with_path(leaf, params_shape)
 

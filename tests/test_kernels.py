@@ -322,6 +322,29 @@ class TestSelectiveScan:
                                        atol=1e-4 * scale, rtol=1e-4,
                                        err_msg=jax.tree_util.keystr(path))
 
+    @pytest.mark.parametrize("impl", ["xla", "chunk_fused", "pallas_fused"])
+    def test_ssm_impl_shapes_the_xla_scan(self, impl):
+        """Every `ssm_impl` scans to the default's values, and only
+        "pallas_fused" tags the chunk scan as a region LEO prices as one
+        kernel."""
+        from repro.core import parse_hlo
+        from repro.models import ssm
+        from repro.models.flags import FUSED_REGION_MARK, flags
+        cfg, p, xin = _scan_inputs(2, 64, 256, 16)
+
+        def scan():  # a new jit each time: the flag is read while tracing
+            return jax.jit(lambda p, x: ssm._xla_scan(p, x, cfg, chunk=16))
+        with jax.default_matmul_precision("highest"):
+            want = scan()(p, xin)
+            with flags(ssm_impl=impl):
+                got = scan()(p, xin)
+                hlo = scan().lower(p, xin).compile().as_text()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-6, rtol=1e-6)
+        marked = [i for i in parse_hlo(hlo).all_instructions()
+                  if FUSED_REGION_MARK in i.op_name]
+        assert bool(marked) == (impl == "pallas_fused")
+
     @pytest.mark.parametrize("s,din,n,chunk", [
         (4096, 3200, 16, 64),   # hymba-1.5b: the backward's VMEM bounds it
         (256, 128, 8, 256),
@@ -332,6 +355,17 @@ class TestSelectiveScan:
     def test_chunk_follows_shapes_and_vmem(self, s, din, n, chunk):
         from repro.kernels.ssm_scan import selective_scan_chunk
         assert selective_scan_chunk(s, din, n) == chunk
+
+
+@pytest.mark.parametrize("name", ["attention_impl", "ssm_impl", "moe_impl"])
+def test_unknown_flag_value_is_refused(name):
+    """A value no path reads (say a typo in the dry-run's `--flags`) raises,
+    and leaves the flags as they were."""
+    from repro.models.flags import ModelFlags, flags, get_flags
+    with pytest.raises(ValueError, match=name):
+        with flags(**{name: "pallas"}):
+            pass
+    assert get_flags() == ModelFlags()
 
 
 class TestScanPathByPlatform:
