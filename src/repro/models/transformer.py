@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..parallel.sharding import batch_over_data
 from . import attention as attn_mod
 from . import moe as moe_mod
 from . import scopes
@@ -193,8 +194,8 @@ def forward(params: Params, cfg: ArchConfig,
     for (desc, reps), stacked in zip(layer_groups(cfg), params["groups"]):
         def body(carry, layer_p, _desc=desc):
             xc, auxc = carry
-            xn, aux = _block_forward(layer_p, xc, _desc, cfg, positions,
-                                     chunk)
+            xn, aux = _block_forward(layer_p, batch_over_data(xc), _desc,
+                                     cfg, positions, chunk)
             return (xn, auxc + aux), None
 
         if remat == "group_save_moe":

@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ArchConfig, ShapeConfig
+from ..kernels.placement import step_mesh
 
 # (path regex, spec builder taking rank) — first match wins.  Specs are
 # written for the *unstacked* trailing dims; a leading scan/stack axis is
@@ -130,6 +131,32 @@ def _axis_size(mesh: Mesh, axis) -> int:
     return mesh.shape[axis]
 
 
+def _data_axes(mesh) -> Tuple[str, ...]:
+    """The mesh's data-parallel axes, outermost first."""
+    return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
+
+
+def batch_over_data(x):
+    """`x` with its leading (batch) axis sharded over the data axes of the
+    mesh its step runs over (`step_mesh(x)`) and its other axes whole.
+
+    A constraint on the layer activations: without it the partitioner,
+    seeing FSDP's d_model-sharded weights, keeps the residual stream
+    d_model-sharded with the whole batch on every chip, and so repeats
+    each row's attention and loss on every data replica and all-reduces
+    activations where it could gather weights.  `x` comes back unchanged
+    off a mesh, on a mesh whose data axes hold one device, and where they
+    do not divide the batch.
+    """
+    mesh = step_mesh(x)
+    dp = _data_axes(mesh)
+    size = math.prod(mesh.shape[a] for a in dp)
+    if size <= 1 or x.shape[0] % size:
+        return x
+    spec = P(dp if len(dp) > 1 else dp[0], *(None,) * (x.ndim - 1))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
 def _auto_shard_dp(spec: P, shape: Tuple[int, ...], mesh: Mesh,
                    dp_axes: Tuple[str, ...],
                    threshold_bytes: int, itemsize: int = 2) -> P:
@@ -163,9 +190,7 @@ class ShardingRules:
         self.cfg = cfg
         self.fsdp = fsdp
         self.zero1 = zero1
-        names = mesh.axis_names
-        self.dp_axes: Tuple[str, ...] = tuple(
-            a for a in names if a in ("pod", "data"))
+        self.dp_axes: Tuple[str, ...] = _data_axes(mesh)
         self.dp_spec = self.dp_axes if len(self.dp_axes) > 1 else \
             self.dp_axes[0]
         self.tp = mesh.shape.get("model", 1)

@@ -12,6 +12,7 @@ workers import every test file.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import parse_hlo
+from repro.core.collectives import COLLECTIVE_OPCODES
 from repro.kernels import ops
 
 
@@ -243,6 +245,34 @@ def test_sharded_step_keeps_chunked_attention(v5e_2x2, monkeypatch):
     text = step.lower(*args).compile().as_text()
     assert not _kernel_scopes(parse_hlo(text), "flash_attention")
     assert _attention_carries(text)
+
+
+def test_sharded_glm_step_keeps_rows_on_their_data_replica(v5e_2x2,
+                                                           monkeypatch):
+    """GLM-4-9B's step, one layer at the cell's 2 x 4096, with
+    `launch/train.py`'s shardings over the whole v5e:2x2 (TP over `model`,
+    FSDP over `data`): each chip attends and takes the MLP for its own row.
+    No collective moves the MLP's hidden activation for both rows, the
+    attention score blocks carry batch 1, and the compiler plans under
+    5.5 GB of temporaries (6.53 GB while every chip held both rows, 4.43 GB
+    when written)."""
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import train_shardings
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_host_mesh(2, devices=v5e_2x2.devices)
+    step, args = _train_step(
+        "glm4-9b", 1, 2, 4096,
+        lambda cfg, state: train_shardings(mesh, cfg, state))
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    collectives = [i for i in parse_hlo(text).all_instructions()
+                   if i.opcode.replace("-start", "") in COLLECTIVE_OPCODES]
+    assert collectives
+    assert not [i.name for i in collectives
+                if "bf16[2,4096,6848]" in str(i.shape)]
+    scores = set(re.findall(r"f32\[(\d+),16,512,512\]", text))
+    assert scores == {"1"}, scores
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.5e9
 
 
 def test_kernel_scan_cuts_hymba_step_temporaries(one_chip, monkeypatch):
